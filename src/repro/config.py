@@ -108,7 +108,9 @@ def set_engine_backend(backend: str | None) -> None:
 # 'fallback' walks the degradation lattice (tuned → default → alternate
 # strategy/backend → reference oracle), 'raise' surfaces a structured
 # error naming the failing site. Same resolution order as the engine
-# backend: session global → $REPRO_ON_FAILURE → default 'fallback'.
+# backend: session global → $REPRO_ON_FAILURE → default 'raise', so a
+# kernel that fails is never silently replaced by the XLA oracle; callers
+# that want degradation ask for 'fallback' by name.
 
 ON_FAILURE_MODES = ("fallback", "raise")
 ON_FAILURE_ENV = "REPRO_ON_FAILURE"
@@ -126,12 +128,12 @@ def resolve_on_failure(mode: str) -> str:
 
 def on_failure() -> str:
     """The session's failure policy: ``set_on_failure()`` if called, else
-    ``$REPRO_ON_FAILURE``, else ``'fallback'``."""
+    ``$REPRO_ON_FAILURE``, else ``'raise'``."""
     import os
 
     if _ON_FAILURE is not None:
         return _ON_FAILURE
-    return resolve_on_failure(os.environ.get(ON_FAILURE_ENV, "fallback"))
+    return resolve_on_failure(os.environ.get(ON_FAILURE_ENV, "raise"))
 
 
 def set_on_failure(mode: str | None) -> None:

@@ -168,9 +168,6 @@ def _tap_read(xb: jnp.ndarray, tap: Tap, valid: tuple[int, ...]) -> jnp.ndarray:
     return xb[tap.row_offset : tap.row_offset + valid[0], :]
 
 
-MXU_TAP_ALIGN = 8       # fp32 sublane tiling: taps pad to (8·k, lanes)
-
-
 def _flat_taps(stage: SystolicPlan) -> list[tuple[int, Tap]]:
     """The tap set flattened to ``(cumulative_shift, tap)`` pairs.
 
@@ -191,89 +188,55 @@ def _apply_plan_mxu(xb, stage: SystolicPlan, w_ref, acc_dtype):
 
     Instead of walking the tap set with per-tap FMAs (the VPU 'lanes'
     schedule), gather every tap's shifted view of the block into a
-    ``(taps, out_elems)`` operand **in VMEM** — im2row over the tap set,
-    never materialized in HBM — pad the tap dimension to the fp32
-    sublane tile (``8·k`` rows, zero rows contribute nothing) and
-    contract it with the coefficient vector in ONE
-    ``jax.lax.dot_general`` with ``preferred_element_type=f32``, which
-    Mosaic routes to the MXU (DESIGN.md §13). The per-lane sums equal
-    the shift_data association, so both strategies agree to fp32
-    tolerance. For NCHW reduce plans this runs once per ``C_in``
-    iterate of the reduce sweep into the same fp32 accumulator: the
-    effective contraction dimension is ``C_in·taps``.
+    ``(rows, taps, lanes)`` operand **in VMEM** — im2row over the tap
+    set, never materialized in HBM — and contract the tap dimension in
+    ONE row-batched ``jax.lax.dot_general`` with
+    ``preferred_element_type=f32`` at ``HIGHEST`` precision (fp32
+    contraction; Mosaic's default may round f32 operands to bf16), which
+    Mosaic routes to the MXU (DESIGN.md §13). Each view keeps the block's
+    full lane width: the lane shift is a roll (the shift_data
+    association, so both strategies agree to fp32 tolerance) and the
+    valid lanes are cropped after the contraction. Mosaic cannot flatten
+    an unaligned view to 1-D, which is why the tap dimension sits between
+    the row and lane dimensions.
 
-    Per-lane coefficient rows ('perlane', depthwise conv1d) have no
-    shared coefficient vector; they contract the tap dimension under a
-    lane-axis *batch* dimension instead — a batched mat-vec, still a
-    single MXU-shaped ``dot_general``.
+    Every tap's coefficient — a compile-time immediate ('table'), a
+    runtime filter scalar ('dense') or a per-lane row ('perlane') — is
+    folded into its view, so all three modes contract against a ones
+    vector. For NCHW reduce plans this runs once per ``C_in`` iterate of
+    the reduce sweep into the same fp32 accumulator.
     """
     exts = stage.exts
     stride = stage.stride_per_axis()
     strided = any(v > 1 for v in stride)
-    taps = _flat_taps(stage)
+    views = []
     if strided:
         sh, sw = stride
         out_sp = tuple((n - e) // v + 1
                        for n, e, v in zip(xb.shape, exts, stride))
-    else:
-        # shift_data coordinates: out lane l ← in lane l + cum, so the
-        # tap view is a static crop — no roll, no valid-lane shuffle.
-        out_sp = tuple(n - (e - 1) for n, e in zip(xb.shape, exts))
-    views = []
-    for cum, tap in taps:
-        if strided:
-            views.append(xb[
+        for cum, tap in _flat_taps(stage):
+            views.append((tap, xb[
                 tap.row_offset : tap.row_offset + out_sp[0] * sh : sh,
                 cum : cum + out_sp[1] * sw : sw,
-            ])
-        elif xb.ndim == 3:
-            views.append(xb[
-                tap.z_offset : tap.z_offset + out_sp[0],
-                tap.row_offset : tap.row_offset + out_sp[1],
-                cum : cum + out_sp[2],
-            ])
-        else:
-            views.append(xb[
-                tap.row_offset : tap.row_offset + out_sp[0],
-                cum : cum + out_sp[1],
-            ])
-    T = len(views)
-    Tp = -(-T // MXU_TAP_ALIGN) * MXU_TAP_ALIGN
-    if stage.coeff_mode == "perlane":
-        # (T, R, L) taps × (T, L) per-lane rows: contract T, batch L.
-        A = jnp.stack(views)
-        Wm = jnp.stack([w_ref[tap.coeff_id[-1], :].astype(acc_dtype)
-                        for _, tap in taps])
-        if Tp != T:
-            A = jnp.pad(A, ((0, Tp - T),) + ((0, 0),) * (A.ndim - 1))
-            Wm = jnp.pad(Wm, ((0, Tp - T), (0, 0)))
-        out = jax.lax.dot_general(
-            Wm, A, dimension_numbers=(((0,), (0,)), ((1,), (2,))),
-            preferred_element_type=jnp.float32)
-        return out.T.astype(acc_dtype)      # (L, R) → (R, L)
-    # (1, 8·k) coefficient row × (8·k, out_elems) im2row operand.
-    if stage.coeff_mode == "table":
-        # Compile-time immediates cannot ride a materialized coefficient
-        # vector (a Pallas kernel may not capture array constants): fold
-        # each scalar into its im2row row and contract with a broadcast
-        # ones row — the same single dot_general over the tap dimension.
-        A = jnp.stack([v.reshape(-1) * stage.coeffs[tap.coeff_id[-1]]
-                       for v, (_, tap) in zip(views, taps)])
-        if Tp != T:
-            A = jnp.pad(A, ((0, Tp - T), (0, 0)))
-        c = jnp.ones((Tp,), acc_dtype)      # splat; zero rows contribute 0
-    else:                                   # dense runtime filter
-        pre = (0,) * (stage.out_axes + stage.reduce_axes)
-        A = jnp.stack([v.reshape(-1) for v in views])
-        c = jnp.stack([w_ref[pre + tap.coeff_id].astype(acc_dtype)
-                       for _, tap in taps])
-        if Tp != T:
-            A = jnp.pad(A, ((0, Tp - T), (0, 0)))
-            c = jnp.pad(c, (0, Tp - T))
-    out = jax.lax.dot_general(
-        c.reshape(1, Tp), A, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return out.reshape(out_sp).astype(acc_dtype)
+            ]))
+    else:
+        out_sp = tuple(n - (e - 1) for n, e in zip(xb.shape, exts))
+        for cum, tap in _flat_taps(stage):
+            xs = jnp.roll(xb, -cum, axis=-1) if cum else xb
+            views.append((tap, _tap_read(xs, tap, out_sp)))
+    A = jnp.stack([v * _coeff(stage, w_ref, tap, acc_dtype)
+                   for tap, v in views], axis=-2)  # (..., rows, taps, lanes)
+    lead = A.shape[:-3]
+    A = A.reshape((-1,) + A.shape[-3:]) if lead else A[None]
+    ones = jnp.ones((A.shape[1], 1, A.shape[2]), acc_dtype)
+    out = jnp.stack([
+        jax.lax.dot_general(
+            ones, a, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)[:, 0, :]
+        for a in A])                                # one batched dot per plane
+    out = out.reshape(lead + out.shape[1:]) if lead else out[0]
+    return out[..., : out_sp[-1]].astype(acc_dtype)
 
 
 def _apply_plan_once(xb, stage: SystolicPlan, w_ref, variant: str, acc_dtype,
@@ -438,6 +401,30 @@ def _window_kernel(*refs, plan: SystolicPlan, block: tuple[int, ...],
         o_ref[o_idx] = epilogue_fn(res).astype(o_ref.dtype)
 
 
+def tpu_tile(dtype) -> tuple[int, int]:
+    """The (sublane, lane) tile Mosaic lays a VMEM block of ``dtype`` in:
+    (8, 128) for 32-bit, (16, 128) for 16-bit, (32, 128) for 8-bit."""
+    return (8 * 4 // jnp.dtype(dtype).itemsize, 128)
+
+
+def _round_block(shape, out_block, grid, tile) -> tuple[int, ...]:
+    """Round the last two dims of an input block up to ``tile`` multiples.
+
+    Mosaic accepts a block whose last two dims are tile multiples or span
+    the whole array. A halo-widened input block such as 10×130 for an
+    8×128 output block is neither, so it reads 16×256 instead. A dim
+    needs no round-up when it carries no halo (it is then as legal as the
+    output block) or when its grid has one step (the caller sizes the
+    operand to exactly that block).
+    """
+    if tile is None:
+        return tuple(shape)
+    lead = tuple(shape[:-2])
+    return lead + tuple(
+        n if n == b or g == 1 else -(-n // t) * t
+        for n, b, g, t in zip(shape[-2:], out_block[-2:], grid[-2:], tile))
+
+
 def _window_call(
     x: jax.Array,
     w,
@@ -451,18 +438,22 @@ def _window_call(
     epilogue_args: tuple,
     make_kernel,
     make_scratch,
+    in_tile: tuple[int, int] | None = None,
 ) -> jax.Array:
     """Backend-shared windowed-family driver (DESIGN.md §14).
 
     Everything about a windowed lowering that is backend-*independent*
     lives here: plan validation, the t-widened origin/halo padding, the
-    overlapped ``pl.Unblocked`` input BlockSpecs, coefficient/epilogue
-    operand layout, the batch × out × spatial × reduce grid, and the
-    final valid crop. A backend contributes only its kernel body and
-    scratch request — ``make_kernel(B)`` → kernel fn for output block
-    ``B``, ``make_scratch(B, in_block)`` → ``scratch_shapes`` list — so
-    the TPU (sublane/lane) and GPU (warp-shuffle + SMEM skirt) lowerings
-    share one geometry and can only differ in how a block is computed.
+    overlapped element-indexed (``pl.Element``) input BlockSpecs,
+    coefficient/epilogue operand layout, the batch × out × spatial ×
+    reduce grid, and the final valid crop. A backend contributes only its
+    kernel body, scratch request and input tile — ``make_kernel(B)`` →
+    kernel fn for output block ``B``, ``make_scratch(B, in_block)`` →
+    ``scratch_shapes`` list, ``in_tile`` → the (sublane, lane) multiple
+    the last two input block dims are rounded up to (None: unrounded) —
+    so the TPU (sublane/lane) and GPU (warp-shuffle + SMEM skirt)
+    lowerings share one geometry and can only differ in how a block is
+    computed.
     """
     nb, nr, no, nd = (plan.batch_axes, plan.reduce_axes, plan.out_axes,
                       plan.ndim_spatial)
@@ -505,8 +496,18 @@ def _window_call(
     # Origin + round-up padding (core.halo): t·lead zeros ahead of the
     # origin, then enough behind so every (including the last) overlapped
     # input block is in-bounds.
-    pads = [(0, 0)] * (nb + nr) + origin_pads(plan, spatial_in, g, B, t)
-    xp = jnp.pad(x, pads)
+    in_block = _round_block(plan.block_in_shape(B, t), B, g, in_tile)
+    # The tiling reads (g−1)·b·stride + in_block rows per axis, which
+    # covers origin_pads' round-up plus any tile round-up of in_block.
+    extent = [(gi - 1) * b * v + ib
+              for gi, b, v, ib in zip(g, B, stride, in_block)]
+    pads = [(0, 0)] * (nb + nr) + [
+        (lo, max(0, e - lo - s)) for (lo, _), e, s in zip(
+            origin_pads(plan, spatial_in, g, B, t), extent, spatial_in)]
+    # Crop what a strided tiling never reads, so a one-step grid's input
+    # block spans exactly the whole operand.
+    xp = jnp.pad(x, pads)[(slice(None),) * (nb + nr)
+                          + tuple(slice(0, e) for e in extent)]
 
     # Grid layout: batch × out × spatial × reduce — reduce innermost so
     # the sweep over it is sequential per output block and the scratch
@@ -522,12 +523,15 @@ def _window_call(
     # are disjoint, input tiles overlap by the halo, so grid steps never
     # communicate (the TPU analogue of the paper's branch-free warp blocks).
     # An output-strided grid reads input tiles at stride-scaled origins.
-    in_block = plan.block_in_shape(B, t)
+    # The kernel crops its result to the output block, so the rows and
+    # lanes the tile round-up adds are read but never stored. A one-step
+    # axis reads at a literal 0: Mosaic cannot prove that i·b is a tile
+    # multiple when b is an unaligned whole-axis block (a halo frame).
     x_spec = pl.BlockSpec(
-        (1,) * (nb + nr) + in_block,
+        tuple(pl.Element(n) for n in (1,) * (nb + nr) + in_block),
         lambda *ids: ids[:nb] + ids[rd0:rd0 + nr] + tuple(
-            i * b * v for i, b, v in zip(ids[sp0:sp0 + nd], B, stride)),
-        indexing_mode=pl.Unblocked(),
+            i * b * v if gi > 1 else 0
+            for i, b, v, gi in zip(ids[sp0:sp0 + nd], B, stride, g)),
     )
     in_specs = [x_spec]
     operands = [xp]
@@ -640,7 +644,7 @@ def _run_window_plan_tpu(
             x, w, plan=plan, block=block, time_steps=time_steps,
             variant=variant, interpret=interpret, acc_dtype=acc_dtype,
             epilogue_args=epilogue_args, make_kernel=make_kernel,
-            make_scratch=make_scratch)
+            make_scratch=make_scratch, in_tile=tpu_tile(x.dtype))
 
 
 def run_window_plan(
@@ -827,8 +831,12 @@ def run_weight_grad_plan(
             (x.shape, g.shape)
         bt, bd = min(block[0], Tg), min(block[1], D)
         gt, gd = pl.cdiv(Tg, bt), pl.cdiv(D, bd)
+        # The lane dim is unhaloed (bd is a lane multiple or all of D);
+        # only the time dim's K−1 halo needs the tile round-up.
+        it = _round_block((bt + K - 1, bd), (bt, bd), (gt, gd),
+                          tpu_tile(x.dtype))[0]
         gp = jnp.pad(g, ((0, 0), (0, gt * bt - Tg), (0, gd * bd - D)))
-        xp = jnp.pad(x, ((0, 0), (lead, gt * bt + K - 1 - lead - T),
+        xp = jnp.pad(x, ((0, 0), (lead, (gt - 1) * bt + it - lead - T),
                          (0, gd * bd - D)))
         kern = functools.partial(_wgrad_perlane_kernel, K=K, block=(bt, bd),
                                  acc_dtype=acc_dtype)
@@ -836,12 +844,10 @@ def run_weight_grad_plan(
             kern,
             grid=(gd, B, gt),               # lanes out; batch × time reduce
             in_specs=[
-                pl.BlockSpec((1, bt + K - 1, bd),
-                             lambda d, b, i: (b, i * bt, d * bd),
-                             indexing_mode=pl.Unblocked()),
-                pl.BlockSpec((1, bt, bd),
-                             lambda d, b, i: (b, i * bt, d * bd),
-                             indexing_mode=pl.Unblocked()),
+                pl.BlockSpec((pl.Element(1), pl.Element(it), pl.Element(bd)),
+                             lambda d, b, i: (b, i * bt if gt > 1 else 0,
+                                              d * bd)),
+                pl.BlockSpec((1, bt, bd), lambda d, b, i: (b, i, d)),
             ],
             out_specs=pl.BlockSpec((K, bd), lambda d, b, i: (0, d)),
             out_shape=jax.ShapeDtypeStruct((K, gd * bd), acc_dtype),
@@ -864,22 +870,24 @@ def run_weight_grad_plan(
     assert Wo == W + lead[1] + trail[1] - (M - 1), (x.shape, g.shape)
     bh, bw = min(block[0], Ho), min(block[1], Wo)
     gh, gw = pl.cdiv(Ho, bh), pl.cdiv(Wo, bw)
+    ih, iw = _round_block((bh + N - 1, bw + M - 1), (bh, bw), (gh, gw),
+                          tpu_tile(x.dtype))
     gp = jnp.pad(g4, ((0, 0), (0, 0), (0, gh * bh - Ho), (0, gw * bw - Wo)))
     xp = jnp.pad(x4, ((0, 0), (0, 0),
-                      (lead[0], gh * bh + N - 1 - lead[0] - H),
-                      (lead[1], gw * bw + M - 1 - lead[1] - W)))
+                      (lead[0], (gh - 1) * bh + ih - lead[0] - H),
+                      (lead[1], (gw - 1) * bw + iw - lead[1] - W)))
     kern = functools.partial(_wgrad_dense_kernel, exts=(N, M),
                              block=(bh, bw), acc_dtype=acc_dtype)
     out = pl.pallas_call(
         kern,
         grid=(C_out, C_in, B, gh, gw),   # channels out; batch×tiles reduce
         in_specs=[
-            pl.BlockSpec((1, 1, bh + N - 1, bw + M - 1),
-                         lambda co, ci, b, i, j: (b, ci, i * bh, j * bw),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec(tuple(pl.Element(n) for n in (1, 1, ih, iw)),
+                         lambda co, ci, b, i, j: (
+                             b, ci, i * bh if gh > 1 else 0,
+                             j * bw if gw > 1 else 0)),
             pl.BlockSpec((1, 1, bh, bw),
-                         lambda co, ci, b, i, j: (b, co, i * bh, j * bw),
-                         indexing_mode=pl.Unblocked()),
+                         lambda co, ci, b, i, j: (b, co, i, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, N, M),
                                lambda co, ci, b, i, j: (co, ci, 0, 0)),

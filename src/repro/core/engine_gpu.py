@@ -45,6 +45,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+# Mosaic-GPU's SMEM memory space; interpret mode on CPU hosts runs it as a
+# faithful stand-in.
+from jax.experimental.pallas import mosaic_gpu as plgpu
 
 from repro.robust import faults as rfaults
 
@@ -52,29 +55,6 @@ from . import engine
 from .plan import (EPILOGUE_OPERANDS, GPU_WARP_LANES, SystolicPlan,
                    chain_epilogue_operand_stages)
 from .fuse import pipeline_coeff_count
-
-try:  # pragma: no cover - import probe
-    # Mosaic-GPU ships with jax's CUDA builds *and* provides a faithful
-    # SMEM memory-space stand-in under interpret mode on CPU hosts.
-    from jax.experimental.pallas import mosaic_gpu as plgpu
-
-    HAS_MOSAIC_GPU = True
-
-    def _smem(shape, dtype):
-        return plgpu.SMEM(shape, dtype)
-
-except ImportError:  # pragma: no cover - CPU-only wheels without mosaic
-    from jax.experimental.pallas import tpu as _pltpu
-
-    HAS_MOSAIC_GPU = False
-
-    def _smem(shape, dtype):
-        # Documented emulation: VMEM scratch stands in for SMEM so the
-        # lowering still runs (interpret mode) when the GPU dialect is
-        # absent from the wheel. Numerics are identical — scratch is a
-        # staging copy either way.
-        return _pltpu.VMEM(shape, dtype)
-
 
 GPU_BLOCK_WARPS = 4      # CUDA-guide default block: 128 threads / 4 warps
 
@@ -285,9 +265,9 @@ def _run_window_plan_gpu_jit(
             variant=variant, acc_dtype=acc_dtype)
 
     def make_scratch(B, in_block):
-        scratch = [_smem(in_block, acc_dtype)]      # halo-skirt staging
+        scratch = [plgpu.SMEM(in_block, acc_dtype)]  # halo-skirt staging
         if plan.reduce_axes:
-            scratch.append(_smem(B, acc_dtype))     # register accumulator
+            scratch.append(plgpu.SMEM(B, acc_dtype))  # register accumulator
         return scratch
 
     with engine._obs_lowering(plan=plan, block=block, backend="gpu",
@@ -397,7 +377,7 @@ def _run_scan_plan_gpu_jit(
                                  want_carry=return_carry)
 
     def make_scratch(BR):
-        return [_smem((BR, 1), acc_dtype)]
+        return [plgpu.SMEM((BR, 1), acc_dtype)]
 
     with engine._obs_lowering(plan=plan, block=(block_r, plan.S),
                               backend="gpu"):

@@ -432,9 +432,30 @@ def _sidecar_store(skey: str, result: TuneResult) -> None:
         save_sidecar()
 
 
+def tile_aligned(plan: SystolicPlan, cfg: KernelConfig, shape,
+                 time_steps: int = 1) -> bool:
+    """Whether every grid offset of ``cfg`` on ``shape`` is a whole number
+    of TPU (8, 128) tiles: each of the block's last two dims is a tile
+    multiple or covers its whole output extent (a one-step grid).
+
+    Every candidate :func:`candidate_configs` builds for the TPU grid is;
+    a neighbor's winner replayed by nearest-shape seeding need not be —
+    a block clamped to a small shape's 96-lane extent tiles a wider one
+    at 96-lane offsets, which Mosaic refuses.
+    """
+    if plan.combine != "fma":
+        out, block = tuple(shape)[-2:], cfg.block[:2]
+    else:
+        spatial = tuple(shape)[plan.batch_axes + plan.reduce_axes:]
+        out, block = plan.out_shape(spatial, time_steps), cfg.block
+    return all(b >= o or b % t == 0
+               for b, o, t in zip(block[-2:], out[-2:], (8, 128)))
+
+
 def _nearest_sidecar(sig: str, shape, time_steps: int, context: tuple,
-                     strategy: str = "auto",
-                     backend: str = "tpu") -> KernelConfig | None:
+                     strategy: str = "auto", backend: str = "tpu",
+                     usable: Callable[[KernelConfig], bool] | None = None,
+                     ) -> KernelConfig | None:
     """The winner of the closest already-tuned shape of the same plan.
 
     Same plan signature, time_steps, platform, context, pinned
@@ -442,9 +463,10 @@ def _nearest_sidecar(sig: str, shape, time_steps: int, context: tuple,
     strategy pin ran a different algorithm, and one tuned against the
     other backend ran a different kernel entirely, so neither may seed
     this one (the v5/v6 key components exist precisely to enforce that).
-    Closest by summed |log| ratio of extents. Seeding replays that
-    winner with no measurement — the engine clamps blocks to the output
-    shape, so the neighbor's config is always runnable on the new shape.
+    Closest by summed |log| ratio of extents, among the winners
+    ``usable`` accepts (the TPU tuner passes :func:`tile_aligned`).
+    Seeding replays that winner with no measurement — the engine clamps
+    blocks to the output shape.
     """
     want = [sig, time_steps, jax.default_backend(), _jsonable(context),
             strategy, backend]
@@ -455,7 +477,8 @@ def _nearest_sidecar(sig: str, shape, time_steps: int, context: tuple,
         except ValueError:      # pre-v6 key arity smuggled past the
             continue            # schema gate: never a seed candidate
         if ([ksig, kt, kplat, kctx, kstrat, kback] != want
-                or len(kshape) != len(shape)):
+                or len(kshape) != len(shape)
+                or (usable is not None and not usable(cfg))):
             continue
         d = sum(abs(math.log(k / s)) for k, s in zip(kshape, shape))
         if best_d is None or d < best_d:
@@ -548,7 +571,10 @@ def candidate_configs(
         out: list[KernelConfig] = []
         for br in _SCAN_BLOCK_R:
             for bt in _SCAN_BLOCK_T:
-                bt_eff = 1 << (min(bt, T).bit_length() - 1)
+                # a sequence shorter than the tile runs as one padded
+                # tile (ssam_scan._lane_tile): every grid offset stays
+                # a whole number of 128-lane TPU tiles
+                bt_eff = min(bt, _next_pow2(T))
                 if not chunked:
                     cfg = KernelConfig((min(br, R), bt_eff))
                     if cfg.block[0] * cfg.block[1] <= vmem_budget:
@@ -875,8 +901,10 @@ def autotune(
         _CACHE[key] = result
         return result
     with obs.span("tuner.seed", cat="tuner", plan=sig, backend=backend):
+        usable = (None if backend == "gpu" else
+                  lambda c: tile_aligned(plan, c, shape, time_steps))
         seed = _nearest_sidecar(sig, shape, time_steps, context, pstrat,
-                                backend)
+                                backend, usable)
     if seed is not None and _agrees(seed):
         obs.metrics.inc("tuner.sidecar_seed", backend)
         result = TuneResult(seed, model_cost(plan, seed, time_steps, hw),

@@ -42,7 +42,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import shard_map as shm
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -517,13 +516,13 @@ def sharded_window_plan(
         acc_dtype=acc_dtype, assigns=assigns, halos=halos, overlap=overlap,
         backend=backend)
 
-    sharded = shm.shard_map(
+    sharded = jax.shard_map(
         lambda xs, *rest: fn(xs, rest[0] if n_w else None,
                              tuple(rest[n_w:])),
         mesh=mesh,
         in_specs=(spec_in,) + w_specs + epi_specs,
         out_specs=spec_out,
-        check_rep=False,
+        check_vma=False,
     )
     rfaults.check("halo.exchange")
     obs.metrics.inc("halo.launch", plan.kind)
@@ -609,12 +608,12 @@ def sharded_weight_grad(
 
     b_names = tuple(a[0] if a else None for a in batch_assigns)
     s_names = tuple(a[0] if a else None for a in assigns)
-    sharded = shm.shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(*b_names, *((None,) * nr), *s_names),
                   P(*b_names, *((None,) * no), *s_names)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     rfaults.check("halo.exchange")
     return sharded(x, g)

@@ -862,6 +862,13 @@ def _shard_tuning_call(plan, x, mesh, in_specs, time_steps, boundary):
         f"{a[0]}:{a[1]}" if a else "-" for a in assigns)
 
 
+def _measured(operands):
+    """``operands`` as the tuner's timing runs see them: values only.
+    Under ``jax.grad`` a traced operand would pull the timed kernel calls
+    into the linearization, which fails; timing needs no derivative."""
+    return jax.lax.stop_gradient(operands)
+
+
 def _tuned_kwargs(plan, shape, call, user_kw, *, time_steps: int = 1,
                   context: tuple = (), chunked: bool = False,
                   default=None, backend=None) -> dict:
@@ -1017,7 +1024,7 @@ def conv2d(x, w, *, mode: str = "same", impl: str | None = None,
         tag = "conv2d_nchw"
         ref_fn = lambda xx, m: ref.conv2d_nchw(xx, w, m)
         plan_fn = lambda: _c2.plan_for_nchw(x.shape, w.shape, mode)
-        kernel = lambda xs, **k: _c2.conv2d_nchw(xs, w, mode=mode, **k)
+        kernel = lambda xs, ws, **k: _c2.conv2d_nchw(xs, ws, mode=mode, **k)
     elif x.ndim == 3:
         if w.ndim != 2:
             raise ValueError(
@@ -1027,15 +1034,16 @@ def conv2d(x, w, *, mode: str = "same", impl: str | None = None,
         tag = "conv2d_batched"
         ref_fn = lambda xx, m: ref.conv2d_batched(xx, w, m)
         plan_fn = lambda: _c2.plan_for_batched(w.shape, mode)
-        kernel = lambda xs, **k: _c2.conv2d_batched(xs, w, mode=mode, **k)
+        kernel = lambda xs, ws, **k: _c2.conv2d_batched(xs, ws, mode=mode,
+                                                        **k)
     else:
         tag = "conv2d"
         ref_fn = lambda xx, m: (ref.conv2d_same(xx, w) if m == "same"
                                 else ref.conv2d_valid(xx, w))
         plan_fn = lambda: _c2.plan_for(w.shape, mode)
-        kernel = lambda xs, **k: (
-            _c2.conv2d_same(xs, w, **k) if mode == "same"
-            else _c2.conv2d_valid(xs, w, **k))
+        kernel = lambda xs, ws, **k: (
+            _c2.conv2d_same(xs, ws, **k) if mode == "same"
+            else _c2.conv2d_valid(xs, ws, **k))
     plan = _strategy_plan(plan_fn(), strategy, "conv2d")
     if stride is not None or epi_stages:
         plan = dataclasses.replace(plan, stride=stride, epilogue=epi_stages)
@@ -1117,11 +1125,12 @@ def _conv2d_engine(x, w, *, plan, kernel, tag, mode, impl, autotune, mesh,
             shape, sctx = _shard_tuning_call(plan, x, mesh, in_specs, 1,
                                              boundary)
             zeros = jnp.zeros(shape, x.dtype)
+            wm, epm = _measured((w, epi_args))
             sharded_kw = {k: kw.pop(k) for k in ("overlap",) if k in kw}
-            call = (lambda **k: kernel(zeros, interpret=interpret,
+            call = (lambda **k: kernel(zeros, wm, interpret=interpret,
                                        backend=backend, **{**pin, **k})) \
-                if plain else _engine_runner(plan, zeros, w, interpret,
-                                             epi_args=epi_args,
+                if plain else _engine_runner(plan, zeros, wm, interpret,
+                                             epi_args=epm,
                                              backend=backend)
             kw = _tuned_kwargs(plan, shape, call, kw,
                                context=(tag, mode, impl) + sctx,
@@ -1133,10 +1142,11 @@ def _conv2d_engine(x, w, *, plan, kernel, tag, mode, impl, autotune, mesh,
         return _guarded_window(tag, cfg, x, w, epi_args, oracle)
     bwd_tune = None
     if autotune:
-        call = (lambda **k: kernel(x, interpret=interpret, backend=backend,
-                                   **{**pin, **k})) \
-            if plain else _engine_runner(plan, x, w, interpret,
-                                         epi_args=epi_args, backend=backend)
+        xm, wm, epm = _measured((x, w, epi_args))
+        call = (lambda **k: kernel(xm, wm, interpret=interpret,
+                                   backend=backend, **{**pin, **k})) \
+            if plain else _engine_runner(plan, xm, wm, interpret,
+                                         epi_args=epm, backend=backend)
         kw = _tuned_kwargs(plan, x.shape, call, kw, context=(tag, mode, impl),
                            backend=backend)
         bwd_tune = ("adjoint", tag, mode, impl)
@@ -1177,11 +1187,12 @@ def conv1d_causal(x, w, *, impl: str | None = None, autotune: bool = False,
     bwd_tune = None
     if autotune:
         pin = {"strategy": plan.strategy} if plan.strategy else {}
-        call = (lambda **k: _c1.conv1d_causal(x, w, interpret=interpret,
+        xm, wm, epm = _measured((x, w, epi_args))
+        call = (lambda **k: _c1.conv1d_causal(xm, wm, interpret=interpret,
                                               backend=backend,
                                               **{**pin, **k})) \
-            if not epi_stages else _engine_runner(plan, x, w, interpret,
-                                                  epi_args=epi_args,
+            if not epi_stages else _engine_runner(plan, xm, wm, interpret,
+                                                  epi_args=epm,
                                                   backend=backend)
         kw = _tuned_kwargs(plan, x.shape, call, kw, context=("conv1d", impl),
                            backend=backend)

@@ -4,6 +4,11 @@ Each function is a direct, obviously-correct statement of the math with
 no systolic structure. Kernel unit tests sweep shapes/dtypes and
 ``assert_allclose`` the Pallas kernels (interpret mode) and the
 :mod:`repro.core.executor` model against these.
+
+Single-field windowed oracles sum their taps in a loop, one shifted
+slice at a time, so they also check chip-sized fields: an unrolled sum
+of 121 slices, or a one-channel ``conv_general_dilated``, asks the TPU
+compiler for tens of GiB at 8192².
 """
 from __future__ import annotations
 
@@ -13,18 +18,31 @@ import jax.numpy as jnp
 from .stencils import StencilDef
 
 
+def _tap_sum(xp: jax.Array, offsets, coeffs, out_shape) -> jax.Array:
+    """``Σ_k coeffs[k] · xp[offsets[k] : offsets[k] + out_shape]`` in f32,
+    one tap per loop iteration."""
+    offsets = jnp.asarray(offsets, jnp.int32).reshape(-1, xp.ndim)
+    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(-1)
+    xp = xp.astype(jnp.float32)
+
+    def tap(k, acc):
+        return acc + coeffs[k] * jax.lax.dynamic_slice(
+            xp, tuple(offsets[k]), out_shape)
+
+    return jax.lax.fori_loop(0, coeffs.shape[0], tap,
+                             jnp.zeros(out_shape, jnp.float32))
+
+
 # ---------------------------------------------------------------------------
 # Convolution
 # ---------------------------------------------------------------------------
 
 def conv2d_valid(x: jax.Array, w: jax.Array) -> jax.Array:
     """Valid cross-correlation: out[y,x] = Σ_{n,m} x[y+n, x+m]·w[n,m]."""
-    return jax.lax.conv_general_dilated(
-        x[None, None].astype(jnp.float32),
-        w[None, None].astype(jnp.float32),
-        window_strides=(1, 1),
-        padding="VALID",
-    )[0, 0].astype(x.dtype)
+    N, M = w.shape
+    offsets = [(n, m) for n in range(N) for m in range(M)]
+    out_shape = (x.shape[0] - N + 1, x.shape[1] - M + 1)
+    return _tap_sum(x, offsets, w, out_shape).astype(x.dtype)
 
 
 def conv2d_same(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -114,17 +132,11 @@ def stencil_iterate(x: jax.Array, sdef: StencilDef, steps: int) -> jax.Array:
     his = [max(o[a] for o in sdef.offsets) for a in range(sdef.ndim)]
     pad = [(steps * -lo, steps * hi) for lo, hi in zip(los, his)]
     xp = jnp.pad(x, pad).astype(jnp.float32)
+    starts = [[d - lo for d, lo in zip(off, los)] for off in sdef.offsets]
     for _ in range(steps):
-        shape = xp.shape
-        new_shape = tuple(s - (hi - lo) for s, lo, hi in zip(shape, los, his))
-        out = jnp.zeros(new_shape, jnp.float32)
-        for off, c in zip(sdef.offsets, sdef.coeffs):
-            sl = tuple(
-                slice(d - lo, d - lo + n)
-                for d, lo, n in zip(off, los, new_shape)
-            )
-            out = out + xp[sl] * c
-        xp = out
+        new_shape = tuple(s - (hi - lo)
+                          for s, lo, hi in zip(xp.shape, los, his))
+        xp = _tap_sum(xp, starts, sdef.coeffs, new_shape)
     return xp.astype(x.dtype)
 
 

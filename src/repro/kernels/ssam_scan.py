@@ -28,8 +28,12 @@ from repro.core.plan import linear_recurrence_plan, scan_plan
 
 
 def _lane_tile(block_t: int, T: int) -> int:
-    """Largest power-of-two lane tile ≤ min(block_t, T)."""
-    return 1 << (min(block_t, T).bit_length() - 1)
+    """Largest power-of-two lane tile ≤ block_t, capped at T's next power
+    of two. A sequence shorter than ``block_t`` then runs as one padded
+    tile that spans the whole operand, never as several tiles narrower
+    than the TPU's 128-lane layout."""
+    return min(1 << (block_t.bit_length() - 1),
+               1 << max(T - 1, 0).bit_length())
 
 
 def cumsum(

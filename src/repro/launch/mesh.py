@@ -9,12 +9,22 @@ the batch axis (DESIGN.md §4).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler propagates
+    shardings from the rule tables' constraints, as this repo's layers
+    expect. ``make_mesh``'s default, ``Explicit`` axes, instead type every
+    array by its sharding and refuses ops such as the embedding gather on
+    a model-sharded table."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -22,7 +32,7 @@ def make_host_mesh(model_axis: int = 1):
     used by tests and the CPU examples."""
     n = jax.device_count()
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return _mesh((data, model_axis), ("data", "model"))
 
 
 def make_domain_mesh(shape: tuple[int, ...]):
@@ -36,4 +46,4 @@ def make_domain_mesh(shape: tuple[int, ...]):
     if not 1 <= len(shape) <= 2:
         raise ValueError(f"domain meshes are 1-D or 2-D, got {shape}")
     names = ("data", "model")[: len(shape)]
-    return jax.make_mesh(tuple(shape), names)
+    return _mesh(tuple(shape), names)

@@ -250,6 +250,9 @@ def main(argv=None):
                          "chunked scan (default: backend pick, DESIGN.md §12)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from repro.config import get_config
     from repro.models import build_model
     from repro.nn.spec import init_params

@@ -61,6 +61,9 @@ def main(argv=None):
     ap.add_argument("--metrics-file", default="")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from repro.checkpointing import CheckpointManager
     from repro.config import ShapeConfig, get_config
     from repro.data import TokenDataset

@@ -6,13 +6,13 @@ oracle`` — and serves the result of the first level that succeeds.  What
 "succeeds" means, and what happens when nothing does, is set by the
 failure policy (``repro.config.on_failure``):
 
-- ``'fallback'`` (the production default): a failing level demotes to
+- ``'fallback'`` (opt-in, by name): a failing level demotes to
   the next one.  Every demotion bumps the ``robust.demotion`` counter
   (label ``op:from->to``) and annotates the open trace span, so
   degradations are observable, never silent.  If every level fails, the
   last *real* error re-raises unchanged (an injected fault or numerics
   trip with no surviving level raises :class:`GuardedExecutionError`).
-- ``'raise'`` (the test-suite default, pinned in tests/conftest.py): an
+- ``'raise'`` (the default, also pinned in tests/conftest.py): an
   injected fault or numerics trip surfaces immediately as a structured
   :class:`GuardedExecutionError` naming the site; any *other* exception
   re-raises completely unchanged, so pre-existing validation errors

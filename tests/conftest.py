@@ -18,9 +18,10 @@ def rng():
 def _strict_guard_policy():
     """Pin the degradation policy to 'raise' for every test.
 
-    The production default is 'fallback' — under it an engine bug would
-    silently demote to the XLA oracle and every engine-vs-reference
-    equivalence test would vacuously pass. Chaos tests opt back into
+    'raise' is also the default; pinning it here keeps a stray
+    ``$REPRO_ON_FAILURE=fallback`` from letting an engine bug silently
+    demote to the XLA oracle, under which every engine-vs-reference
+    equivalence test would vacuously pass. Chaos tests opt into
     fallback explicitly via ``robust.failure_policy('fallback')``.
     Also guarantees no armed fault site leaks across tests.
     """

@@ -67,6 +67,7 @@ MULTIDEV = textwrap.dedent("""
     from repro.distributed.sharding import (mesh_context, shardings_for_specs,
                                             pspec_for_axes)
     from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_host_mesh
     cfg = get_config("gemma3_1b", smoke=True)
     model = build_model(cfg)
     params = init_params(model.specs(), jax.random.PRNGKey(0))
@@ -76,7 +77,7 @@ MULTIDEV = textwrap.dedent("""
     # single-device loss
     l0 = float(jax.jit(model.loss)(params, batch))
     # sharded loss on (4 data, 2 model)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model_axis=2)
     with mesh, mesh_context(mesh):
         psh = shardings_for_specs(model.specs(), mesh)
         p = jax.device_put(params, psh)
@@ -101,24 +102,30 @@ class TestMultiDevice:
             import jax, jax.numpy as jnp, numpy as np
             from functools import partial
             from repro.distributed.compress import psum_int8_ef
-            import jax.experimental.shard_map as shm
             from jax.sharding import PartitionSpec as P
             mesh = jax.make_mesh((8,), ("data",))
             target = jnp.arange(8.0)
 
-            @partial(shm.shard_map, mesh=mesh, in_specs=(P(), P("data"), P()),
-                     out_specs=(P(), P()), check_rep=False)
+            @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P("data"), P()),
+                     out_specs=(P(), P()), check_vma=False)
             def step(w, x, err):
                 # per-shard gradient of 0.5*(w - target_mean_over_shard)^2
                 g = (w - x.mean()) / 1.0
                 g, err = psum_int8_ef(g, err, "data")
                 return g, err
 
-            w = jnp.zeros(())
-            err = jnp.zeros(())
-            for i in range(300):
-                g, err = step(w, target, err)
-                w = w - 0.1 * g
+            # all 300 steps in one program: one execution in flight, so
+            # the CPU collectives' device threads never wait on another
+            # step's rendezvous
+            @jax.jit
+            def train(w, err):
+                def body(_, carry):
+                    w, err = carry
+                    g, err = step(w, target, err)
+                    return w - 0.1 * g, err
+                return jax.lax.fori_loop(0, 300, body, (w, err))
+
+            w, err = train(jnp.zeros(()), jnp.zeros(()))
             resid = abs(float(w) - float(target.mean()))
             assert resid < 1e-2, resid
             print("ok", resid)
@@ -131,12 +138,11 @@ class TestMultiDevice:
             import jax, jax.numpy as jnp
             from functools import partial
             from repro.distributed.compress import psum_bf16
-            import jax.experimental.shard_map as shm
             from jax.sharding import PartitionSpec as P
             mesh = jax.make_mesh((8,), ("data",))
 
-            @partial(shm.shard_map, mesh=mesh, in_specs=P("data"),
-                     out_specs=P(), check_rep=False)
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
+                     out_specs=P(), check_vma=False)
             def total(x):
                 return psum_bf16(x.sum(), "data")
 

@@ -685,6 +685,32 @@ class TestMxuStrategy:
         finally:
             tuning.clear_sidecar()
 
+    def test_nearest_seed_skips_unaligned_block(self):
+        """A winner clamped to a small shape's 96-lane extent would tile
+        a wider shape at 96-lane offsets, which Mosaic refuses: the TPU
+        tuner seeds from the nearest *tile-aligned* winner instead."""
+        sdef = BENCHMARKS["2d9pt"]
+        plan = stencil2d_plan(sdef.offsets, coeffs=sdef.coeffs)
+        sig = tuning.plan_signature(plan)
+        clamped = tuning.KernelConfig((8, 96), "shift_psum")
+        aligned = tuning.KernelConfig((16, 128), "shift_psum")
+        assert tuning.tile_aligned(plan, clamped, (96, 96))
+        assert not tuning.tile_aligned(plan, clamped, (96, 512))
+        assert tuning.tile_aligned(plan, aligned, (96, 512))
+        tuning.clear_sidecar()
+        try:
+            tuning._SIDECAR[tuning._sidecar_key(
+                sig, (96, 96), 1, (), "auto")] = (clamped, 1.0, 2.0)
+            tuning._SIDECAR[tuning._sidecar_key(
+                sig, (16, 4096), 1, (), "auto")] = (aligned, 1.0, 2.0)
+            usable = lambda c: tuning.tile_aligned(plan, c, (96, 512))
+            assert tuning._nearest_sidecar(
+                sig, (96, 512), 1, (), "auto") == clamped
+            assert tuning._nearest_sidecar(
+                sig, (96, 512), 1, (), "auto", "tpu", usable) == aligned
+        finally:
+            tuning.clear_sidecar()
+
     def test_stale_v5_sidecar_entries_ignored(self, tmp_path):
         """v5 sidecars predate the backend dimension (6-component keys,
         schema 5): the loader and the checkpoint merge path must drop

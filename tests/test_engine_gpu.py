@@ -324,22 +324,19 @@ class TestBackendDispatch:
         assert_close(g, ops.pipeline(x, ["2d5pt", (w, "gelu")], impl="xla"),
                      2e-4)
 
-    def test_smem_staging_requested(self):
-        """The GPU lowering requests an SMEM (or documented VMEM stand-in)
-        staging buffer — the §14 skirt-through-shared-memory discipline."""
+    def test_smem_staging_requested(self, monkeypatch):
+        """The GPU lowering requests an SMEM staging buffer — the §14
+        skirt-through-shared-memory discipline."""
         scratch = []
         sdef = BENCHMARKS["2d5pt"]
         plan = stencil2d_plan(sdef.offsets, coeffs=sdef.coeffs)
-        orig = engine_gpu._smem
+        orig = engine_gpu.plgpu.SMEM
 
         def spy(shape, dtype):
             scratch.append(shape)
             return orig(shape, dtype)
 
-        engine_gpu._smem = spy
-        try:
-            x = jnp.zeros((16, 64), jnp.float32)
-            run_window_plan_gpu(x, plan=plan, block=(8, 32))
-        finally:
-            engine_gpu._smem = spy and orig
+        monkeypatch.setattr(engine_gpu.plgpu, "SMEM", spy)
+        x = jnp.zeros((16, 64), jnp.float32)
+        run_window_plan_gpu(x, plan=plan, block=(8, 32))
         assert scratch and scratch[0] == plan.block_in_shape((8, 32), 1)

@@ -239,6 +239,33 @@ class TestGuardLattice:
         with pytest.raises(ValueError, match="no execution levels"):
             guard.run("op", [])
 
+    @pytest.mark.parametrize("env,want", [(None, "raise"),
+                                          ("fallback", "fallback"),
+                                          ("raise", "raise")])
+    def test_default_policy_is_raise(self, monkeypatch, env, want):
+        """With no session pin, a failing kernel raises unless the
+        caller asks for 'fallback' by name."""
+        from repro import config
+        monkeypatch.setattr(config, "_ON_FAILURE", None)
+        if env is None:
+            monkeypatch.delenv(config.ON_FAILURE_ENV, raising=False)
+        else:
+            monkeypatch.setenv(config.ON_FAILURE_ENV, env)
+        assert guard.on_failure() == want
+
+    def test_default_policy_surfaces_kernel_failure(self, monkeypatch):
+        from repro import config
+        monkeypatch.setattr(config, "_ON_FAILURE", None)
+        monkeypatch.delenv(config.ON_FAILURE_ENV, raising=False)
+
+        def bad_kernel():
+            raise RuntimeError("Mosaic refused the kernel")
+
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            guard.run("stencil", [("tuned", bad_kernel),
+                                  ("oracle", lambda: 0)])
+        assert obs.metrics.counter_total("robust.demotion") == 0
+
 
 # ---------------------------------------------------------------------------
 # Per-site chaos matrix over the real ops surfaces
