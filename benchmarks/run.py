@@ -1181,9 +1181,11 @@ def main(argv=None) -> None:
              "throughput under transient step faults (the BENCH_10.json "
              "artifact)")
     p.add_argument(
-        "--trace", default=None, metavar="PATH",
-        help="collect engine/tuner/halo spans for the whole run and write "
-             "Chrome-trace JSON (chrome://tracing / Perfetto) to PATH")
+        "--trace", default=None, metavar="DIR",
+        help="record a jax.profiler trace of the whole run under DIR "
+             "(DIR/plugins/profile/<run>/<host>.xplane.pb, which "
+             "TensorBoard and Perfetto open), with the engine/tuner/halo "
+             "spans on its host timeline")
     p.add_argument(
         "--metrics", default=None, metavar="PATH",
         help="write the metrics registry snapshot + drift recorder state "
@@ -1199,47 +1201,52 @@ def main(argv=None) -> None:
     if args.json:
         _JSON_ROWS = []
     from repro import obs
-    if args.trace:
-        obs.trace.enable(args.trace)
     try:
-        if args.mesh:
-            shape = tuple(int(v) for v in args.mesh.lower().split("x"))
-            bench_sharded(shape, time_steps=args.time_steps)
-        elif args.grad:
-            bench_grad()
-        elif args.fused:
-            bench_fused()
-        elif args.scan_chunked:
-            bench_scan_chunked()
-        elif args.strategy:
-            bench_strategy(args.strategy)
-        elif args.backend:
-            bench_backend(args.backend)
-        elif args.obs:
-            bench_obs()
-        elif args.chaos:
-            bench_chaos()
-        elif args.batch is not None or args.channels is not None:
-            ch = tuple(int(v) for v in (args.channels or "3,8").split(","))
-            bench_conv2d_batched(args.batch if args.batch is not None else 4,
-                                 ch)
-        else:
-            bench_perf_model()
-            bench_conv2d_filter_sweep()
-            bench_stencil_suite()
-            bench_temporal_blocking()
-            bench_scan()
-            bench_autotune()
-            bench_fused()
-            bench_lm_roofline()
-    finally:
         if args.trace:
-            out = obs.trace.export(args.trace)
-            print(f"# wrote {len(obs.trace.events())} spans to {out}")
+            with obs.tracing(args.trace):
+                _run_mode(args)
+            print(f"# wrote a profiler trace under {args.trace}")
+        else:
+            _run_mode(args)
+    finally:
         if args.metrics:
             print(f"# wrote metrics+drift to {obs.metrics.export(args.metrics)}")
         if args.json:
             _write_json(args.json)
+
+
+def _run_mode(args):
+    """Run the benchmarks the mode flags select."""
+    if args.mesh:
+        shape = tuple(int(v) for v in args.mesh.lower().split("x"))
+        bench_sharded(shape, time_steps=args.time_steps)
+    elif args.grad:
+        bench_grad()
+    elif args.fused:
+        bench_fused()
+    elif args.scan_chunked:
+        bench_scan_chunked()
+    elif args.strategy:
+        bench_strategy(args.strategy)
+    elif args.backend:
+        bench_backend(args.backend)
+    elif args.obs:
+        bench_obs()
+    elif args.chaos:
+        bench_chaos()
+    elif args.batch is not None or args.channels is not None:
+        ch = tuple(int(v) for v in (args.channels or "3,8").split(","))
+        bench_conv2d_batched(args.batch if args.batch is not None else 4,
+                             ch)
+    else:
+        bench_perf_model()
+        bench_conv2d_filter_sweep()
+        bench_stencil_suite()
+        bench_temporal_blocking()
+        bench_scan()
+        bench_autotune()
+        bench_fused()
+        bench_lm_roofline()
 
 
 if __name__ == "__main__":

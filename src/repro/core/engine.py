@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
+from repro.obs import scopes
 from repro.robust import faults as rfaults
 
 from .fuse import pipeline_coeff_count
@@ -506,8 +507,9 @@ def _window_call(
             origin_pads(plan, spatial_in, g, B, t), extent, spatial_in)]
     # Crop what a strided tiling never reads, so a one-step grid's input
     # block spans exactly the whole operand.
-    xp = jnp.pad(x, pads)[(slice(None),) * (nb + nr)
-                          + tuple(slice(0, e) for e in extent)]
+    with jax.named_scope(scopes.ENGINE_PAD):
+        xp = jnp.pad(x, pads)[(slice(None),) * (nb + nr)
+                              + tuple(slice(0, e) for e in extent)]
 
     # Grid layout: batch × out × spatial × reduce — reduce innermost so
     # the sweep over it is sequential per output block and the scratch
@@ -553,7 +555,8 @@ def _window_call(
         operands.append(w)
     elif plan.coeff_mode == "perlane":
         assert w.shape[-1] == spatial_in[-1], (w.shape, spatial_in)
-        wp = jnp.pad(w, ((0, 0), (0, g[-1] * B[-1] - w.shape[-1])))
+        with jax.named_scope(scopes.ENGINE_PAD):
+            wp = jnp.pad(w, ((0, 0), (0, g[-1] * B[-1] - w.shape[-1])))
         in_specs.append(
             pl.BlockSpec((w.shape[0], B[-1]),
                          lambda *ids: (0, ids[sp0 + nd - 1])))
@@ -570,7 +573,8 @@ def _window_call(
                 operands.append(arr)
             elif plan.coeff_mode == "perlane" and not plan.stages:
                 assert arr.shape == (spatial_in[-1],), (arr.shape, spatial_in)
-                bp = jnp.pad(arr, (0, g[-1] * B[-1] - arr.shape[-1]))
+                with jax.named_scope(scopes.ENGINE_PAD):
+                    bp = jnp.pad(arr, (0, g[-1] * B[-1] - arr.shape[-1]))
                 in_specs.append(pl.BlockSpec(
                     (B[-1],), lambda *ids: (ids[sp0 + nd - 1],)))
                 operands.append(bp)
@@ -578,30 +582,35 @@ def _window_call(
                 assert arr.size == 1, ("scalar bias expected for "
                                        f"{plan.kind!r}", arr.shape)
                 in_specs.append(pl.BlockSpec((1,), lambda *ids: (0,)))
-                operands.append(jnp.reshape(arr, (1,)))
+                with jax.named_scope(scopes.ENGINE_PAD):
+                    operands.append(jnp.reshape(arr, (1,)))
         else:                           # residual_add: output layout
             assert arr.shape == batch_dims + out_dims + out_sp, (
                 arr.shape, batch_dims + out_dims + out_sp)
-            rp = jnp.pad(arr, [(0, 0)] * (nb + no) + [
-                (0, gi * bi - o) for gi, bi, o in zip(g, B, out_sp)])
+            with jax.named_scope(scopes.ENGINE_PAD):
+                rp = jnp.pad(arr, [(0, 0)] * (nb + no) + [
+                    (0, gi * bi - o) for gi, bi, o in zip(g, B, out_sp)])
             in_specs.append(pl.BlockSpec(
                 (1,) * (nb + no) + B, lambda *ids: ids[:rd0]))
             operands.append(rp)
 
-    out = pl.pallas_call(
-        make_kernel(B),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1,) * (nb + no) + B,
-                               lambda *ids: ids[:rd0]),
-        out_shape=jax.ShapeDtypeStruct(
-            batch_dims + out_dims + tuple(gi * bi for gi, bi in zip(g, B)),
-            x.dtype),
-        scratch_shapes=make_scratch(B, in_block),
-        interpret=interpret,
-    )(*operands)
-    return out[(slice(None),) * (nb + no)
-               + tuple(slice(0, o) for o in out_sp)]
+    with jax.named_scope(scopes.ENGINE_KERNEL):
+        out = pl.pallas_call(
+            make_kernel(B),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1,) * (nb + no) + B,
+                                   lambda *ids: ids[:rd0]),
+            out_shape=jax.ShapeDtypeStruct(
+                batch_dims + out_dims
+                + tuple(gi * bi for gi, bi in zip(g, B)), x.dtype),
+            scratch_shapes=make_scratch(B, in_block),
+            interpret=interpret,
+            name=scopes.WINDOW_KERNEL,
+        )(*operands)
+    with jax.named_scope(scopes.ENGINE_CROP):
+        return out[(slice(None),) * (nb + no)
+                   + tuple(slice(0, o) for o in out_sp)]
 
 
 @functools.partial(
@@ -706,7 +715,11 @@ def run_window_plan(
     eff = dataclasses.replace(plan, strategy=strategy) if strategy else plan
     strat = (eff.strategy or "lanes") if eff.combine == "fma" else eff.combine
     rfaults.check("engine.window")
-    obs.metrics.inc("engine.launch", f"{backend}:{strat}")
+    # Under a jit trace this body runs once per compilation and launches
+    # nothing; only an eager call dispatches the kernel.
+    eager = not isinstance(x, jax.core.Tracer)
+    if eager:
+        obs.metrics.inc("engine.launch", f"{backend}:{strat}")
     t0 = time.perf_counter()
     with obs.span("engine.run_window_plan", cat="engine", kind=plan.kind,
                   backend=backend, strategy=strat):
@@ -716,7 +729,7 @@ def run_window_plan(
             out = engine_gpu.run_window_plan_gpu(x, w, **kw)
         else:
             out = _run_window_plan_tpu(x, w, **kw)
-    if obs.drift.per_call() and not isinstance(x, jax.core.Tracer):
+    if eager and obs.drift.per_call():
         _obs_call_drift(eff, block, backend, time_steps, variant, out, t0,
                         x.shape)
     return out
@@ -835,26 +848,31 @@ def run_weight_grad_plan(
         # only the time dim's K−1 halo needs the tile round-up.
         it = _round_block((bt + K - 1, bd), (bt, bd), (gt, gd),
                           tpu_tile(x.dtype))[0]
-        gp = jnp.pad(g, ((0, 0), (0, gt * bt - Tg), (0, gd * bd - D)))
-        xp = jnp.pad(x, ((0, 0), (lead, (gt - 1) * bt + it - lead - T),
-                         (0, gd * bd - D)))
+        with jax.named_scope(scopes.ENGINE_PAD):
+            gp = jnp.pad(g, ((0, 0), (0, gt * bt - Tg), (0, gd * bd - D)))
+            xp = jnp.pad(x, ((0, 0), (lead, (gt - 1) * bt + it - lead - T),
+                             (0, gd * bd - D)))
         kern = functools.partial(_wgrad_perlane_kernel, K=K, block=(bt, bd),
                                  acc_dtype=acc_dtype)
-        out = pl.pallas_call(
-            kern,
-            grid=(gd, B, gt),               # lanes out; batch × time reduce
-            in_specs=[
-                pl.BlockSpec((pl.Element(1), pl.Element(it), pl.Element(bd)),
-                             lambda d, b, i: (b, i * bt if gt > 1 else 0,
-                                              d * bd)),
-                pl.BlockSpec((1, bt, bd), lambda d, b, i: (b, i, d)),
-            ],
-            out_specs=pl.BlockSpec((K, bd), lambda d, b, i: (0, d)),
-            out_shape=jax.ShapeDtypeStruct((K, gd * bd), acc_dtype),
-            scratch_shapes=[pltpu.VMEM((K, bd), acc_dtype)],
-            interpret=interpret,
-        )(xp, gp)
-        return out[:, :D]
+        with jax.named_scope(scopes.ENGINE_KERNEL):
+            out = pl.pallas_call(
+                kern,
+                grid=(gd, B, gt),           # lanes out; batch × time reduce
+                in_specs=[
+                    pl.BlockSpec(
+                        (pl.Element(1), pl.Element(it), pl.Element(bd)),
+                        lambda d, b, i: (b, i * bt if gt > 1 else 0,
+                                         d * bd)),
+                    pl.BlockSpec((1, bt, bd), lambda d, b, i: (b, i, d)),
+                ],
+                out_specs=pl.BlockSpec((K, bd), lambda d, b, i: (0, d)),
+                out_shape=jax.ShapeDtypeStruct((K, gd * bd), acc_dtype),
+                scratch_shapes=[pltpu.VMEM((K, bd), acc_dtype)],
+                interpret=interpret,
+                name=scopes.WGRAD_KERNEL,
+            )(xp, gp)
+        with jax.named_scope(scopes.ENGINE_CROP):
+            return out[:, :D]
 
     assert plan.coeff_mode == "dense" and plan.ndim_spatial == 2, plan.kind
     assert no == nr, (no, nr)            # plain dense (0,0) or NCHW (1,1)
@@ -872,30 +890,35 @@ def run_weight_grad_plan(
     gh, gw = pl.cdiv(Ho, bh), pl.cdiv(Wo, bw)
     ih, iw = _round_block((bh + N - 1, bw + M - 1), (bh, bw), (gh, gw),
                           tpu_tile(x.dtype))
-    gp = jnp.pad(g4, ((0, 0), (0, 0), (0, gh * bh - Ho), (0, gw * bw - Wo)))
-    xp = jnp.pad(x4, ((0, 0), (0, 0),
-                      (lead[0], (gh - 1) * bh + ih - lead[0] - H),
-                      (lead[1], (gw - 1) * bw + iw - lead[1] - W)))
+    with jax.named_scope(scopes.ENGINE_PAD):
+        gp = jnp.pad(g4, ((0, 0), (0, 0), (0, gh * bh - Ho),
+                          (0, gw * bw - Wo)))
+        xp = jnp.pad(x4, ((0, 0), (0, 0),
+                          (lead[0], (gh - 1) * bh + ih - lead[0] - H),
+                          (lead[1], (gw - 1) * bw + iw - lead[1] - W)))
     kern = functools.partial(_wgrad_dense_kernel, exts=(N, M),
                              block=(bh, bw), acc_dtype=acc_dtype)
-    out = pl.pallas_call(
-        kern,
-        grid=(C_out, C_in, B, gh, gw),   # channels out; batch×tiles reduce
-        in_specs=[
-            pl.BlockSpec(tuple(pl.Element(n) for n in (1, 1, ih, iw)),
-                         lambda co, ci, b, i, j: (
-                             b, ci, i * bh if gh > 1 else 0,
-                             j * bw if gw > 1 else 0)),
-            pl.BlockSpec((1, 1, bh, bw),
-                         lambda co, ci, b, i, j: (b, co, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, N, M),
-                               lambda co, ci, b, i, j: (co, ci, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((C_out, C_in, N, M), acc_dtype),
-        scratch_shapes=[pltpu.VMEM((N, M), acc_dtype)],
-        interpret=interpret,
-    )(xp, gp)
-    return out if no else out[0, 0]
+    with jax.named_scope(scopes.ENGINE_KERNEL):
+        out = pl.pallas_call(
+            kern,
+            grid=(C_out, C_in, B, gh, gw),  # channels out; batch×tiles reduce
+            in_specs=[
+                pl.BlockSpec(tuple(pl.Element(n) for n in (1, 1, ih, iw)),
+                             lambda co, ci, b, i, j: (
+                                 b, ci, i * bh if gh > 1 else 0,
+                                 j * bw if gw > 1 else 0)),
+                pl.BlockSpec((1, 1, bh, bw),
+                             lambda co, ci, b, i, j: (b, co, i, j)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, N, M),
+                                   lambda co, ci, b, i, j: (co, ci, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((C_out, C_in, N, M), acc_dtype),
+            scratch_shapes=[pltpu.VMEM((N, M), acc_dtype)],
+            interpret=interpret,
+            name=scopes.WGRAD_KERNEL,
+        )(xp, gp)
+    with jax.named_scope(scopes.ENGINE_CROP):
+        return out if no else out[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -995,17 +1018,17 @@ def _scan_call(
     BR = min(block_r, R)
     gr, gt = pl.cdiv(R, BR), pl.cdiv(T, BT)
     pad = ((0, gr * BR - R), (0, gt * BT - T))
-    if plan.combine == "linrec":
-        a, b = operands
-        assert a.shape == b.shape
-        padded = [jnp.pad(a, pad, constant_values=1), jnp.pad(b, pad)]
-    else:
-        padded = [jnp.pad(operands[0], pad)]
-
     has_carry = carry is not None
-    if has_carry:
-        c = carry.reshape(R, 1).astype(operands[0].dtype)
-        padded.append(jnp.pad(c, ((0, gr * BR - R), (0, 0))))
+    with jax.named_scope(scopes.ENGINE_PAD):
+        if plan.combine == "linrec":
+            a, b = operands
+            assert a.shape == b.shape
+            padded = [jnp.pad(a, pad, constant_values=1), jnp.pad(b, pad)]
+        else:
+            padded = [jnp.pad(operands[0], pad)]
+        if has_carry:
+            c = carry.reshape(R, 1).astype(operands[0].dtype)
+            padded.append(jnp.pad(c, ((0, gr * BR - R), (0, 0))))
 
     kern = make_kernel(has_carry)
     in_specs = [pl.BlockSpec((BR, BT), lambda i, j: (i, j))] * (len(padded)
@@ -1020,19 +1043,22 @@ def _scan_call(
         out_specs = (out_specs, pl.BlockSpec((BR, 1), lambda i, j: (i, 0)))
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((gr * BR, 1), operands[0].dtype))
-    res = pl.pallas_call(
-        kern,
-        grid=(gr, gt),                    # T sequential per row-tile
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=make_scratch(BR),
-        interpret=interpret,
-    )(*padded)
-    if return_carry:
-        out, co = res
-        return out[:R, :T], co[:R]
-    return res[:R, :T]
+    with jax.named_scope(scopes.ENGINE_KERNEL):
+        res = pl.pallas_call(
+            kern,
+            grid=(gr, gt),                # T sequential per row-tile
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=make_scratch(BR),
+            interpret=interpret,
+            name=scopes.SCAN_KERNEL,
+        )(*padded)
+    with jax.named_scope(scopes.ENGINE_CROP):
+        if return_carry:
+            out, co = res
+            return out[:R, :T], co[:R]
+        return res[:R, :T]
 
 
 @functools.partial(
@@ -1102,7 +1128,9 @@ def run_scan_plan(
     kw = dict(plan=plan, block_r=block_r, interpret=interpret,
               acc_dtype=acc_dtype, carry=carry, return_carry=return_carry)
     rfaults.check("engine.scan")
-    obs.metrics.inc("engine.launch", f"{backend}:{plan.combine}")
+    eager = not isinstance(operands[0], jax.core.Tracer)
+    if eager:                # a jit trace lowers; only an eager call runs
+        obs.metrics.inc("engine.launch", f"{backend}:{plan.combine}")
     t0 = time.perf_counter()
     with obs.span("engine.run_scan_plan", cat="engine", kind=plan.kind,
                   backend=backend, strategy=plan.combine):
@@ -1112,8 +1140,7 @@ def run_scan_plan(
             out = engine_gpu.run_scan_plan_gpu(*operands, **kw)
         else:
             out = _run_scan_plan_tpu(*operands, **kw)
-    if (obs.drift.per_call()
-            and not isinstance(operands[0], jax.core.Tracer)):
+    if eager and obs.drift.per_call():
         _obs_call_drift(plan, (block_r, plan.S), backend, 1, "shift_psum",
                         out, t0, operands[0].shape)
     return out

@@ -45,6 +45,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
+from repro.obs import scopes
 from repro.core.engine import run_weight_grad_plan, run_window_plan
 from repro.robust import faults as rfaults
 from repro.core.halo import (check_shard_geometry, extended_crop,
@@ -225,10 +226,11 @@ def _halo_slab(x, axis: int, width: int, assign, boundary: str, *,
 
 def _extend_axis(x, axis: int, lo: int, hi: int, assign, boundary: str):
     """Halo-extend ``x`` along one axis (no-op when nothing to add)."""
-    front = _halo_slab(x, axis, lo, assign, boundary, front=True)
-    back = _halo_slab(x, axis, hi, assign, boundary, front=False)
-    parts = [p for p in (front, x, back) if p is not None]
-    return x if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+    with jax.named_scope(scopes.HALO_EXCHANGE):
+        front = _halo_slab(x, axis, lo, assign, boundary, front=True)
+        back = _halo_slab(x, axis, hi, assign, boundary, front=False)
+        parts = [p for p in (front, x, back) if p is not None]
+        return x if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
 
 
 # ---------------------------------------------------------------------------
@@ -294,10 +296,11 @@ def _local_lowering(
         assign = assigns[a]
         if (lo or hi) and assign is not None and assign[1] > 1:
             # A cross-device exchange on this axis. This runs inside the
-            # shard_map trace, so the span and counters fire once per
-            # compilation with *static* accounting: per-shard slab bytes
-            # (both sides) and the ppermute hop count (halos wider than
-            # a shard chain ceil(width/n) hops, _multihop_slab).
+            # shard_map trace, so the span and the halo.exchanges /
+            # halo.bytes counters fire once per compilation, not per
+            # call, with *static* accounting: per-shard slab bytes (both
+            # sides) and the ppermute hop count (halos wider than a
+            # shard chain ceil(width/n) hops, _multihop_slab).
             n = ext.shape[in_off + a]
             slab_bytes = ((lo + hi) * (ext.size // max(n, 1))
                           * ext.dtype.itemsize)
@@ -327,12 +330,13 @@ def _local_lowering(
     def cropped(e):
         """Engine output on a (partially) extended slab, mapped back to
         the rows the slab's un-extended origin owns."""
-        out = engine(e, wl) if wl is not None else engine(e)
-        sl = tuple(
-            extended_crop(plan, time_steps, a, local[a])
-            if a in exchanged else slice(0, local[a])
-            for a in range(nd))
-        return out[pre_out + sl]
+        with jax.named_scope(scopes.HALO_INTERIOR):
+            out = engine(e, wl) if wl is not None else engine(e)
+            sl = tuple(
+                extended_crop(plan, time_steps, a, local[a])
+                if a in exchanged else slice(0, local[a])
+                for a in range(nd))
+            return out[pre_out + sl]
 
     if not exchanged:
         return cropped(ext)
@@ -346,8 +350,8 @@ def _local_lowering(
     # Overlapped schedule: the interior lowers from the *resident* block
     # (no data dependence on the in-flight ppermutes), the frame lowers
     # from halo-extended slabs once they land.
-    interior = engine(xl, wl) if wl is not None else engine(xl)
-    out = interior
+    with jax.named_scope(scopes.HALO_INTERIOR):
+        out = engine(xl, wl) if wl is not None else engine(xl)
     for region in _frame_regions(local, halos, exchanged):
         slab_sl, out_sl, strip_crop = [], [], []
         for a, (lo_r, hi_r) in enumerate(region):
@@ -362,10 +366,12 @@ def _local_lowering(
             else:
                 slab_sl.append(slice(None))
                 strip_crop.append(slice(lo_r, hi_r))
-        strip = ext[pre_in + tuple(slab_sl)]
-        s_out = engine(strip, wl) if wl is not None else engine(strip)
-        out = out.at[pre_out + tuple(out_sl)].set(
-            s_out[pre_out + tuple(strip_crop)])
+        with jax.named_scope(scopes.HALO_FRAME):
+            strip = ext[pre_in + tuple(slab_sl)]
+            s_out = engine(strip, wl) if wl is not None else engine(strip)
+        with jax.named_scope(scopes.HALO_SPLICE):
+            out = out.at[pre_out + tuple(out_sl)].set(
+                s_out[pre_out + tuple(strip_crop)])
     return out
 
 
@@ -525,7 +531,8 @@ def sharded_window_plan(
         check_vma=False,
     )
     rfaults.check("halo.exchange")
-    obs.metrics.inc("halo.launch", plan.kind)
+    if not isinstance(x, jax.core.Tracer):   # under jit: a trace, no launch
+        obs.metrics.inc("halo.launch", plan.kind)
     with obs.span("halo.sharded_window_plan", cat="halo", kind=plan.kind,
                   devices=mesh.size, overlap=overlap, boundary=boundary):
         return sharded(x, *w_args, *epi)

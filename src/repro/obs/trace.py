@@ -1,18 +1,22 @@
-"""Nestable span tracer with Chrome-trace/Perfetto JSON export.
+"""Span tracer on the JAX profiler's clock.
 
-Spans are wall-clock intervals with string attributes, collected into a
-process-wide buffer and exported as Chrome ``traceEvents`` (``ph: "X"``
-complete events — ``chrome://tracing`` and https://ui.perfetto.dev both
-open the file directly). Nesting is per-thread: a thread-local stack
-records the enclosing span, so events carry their parent's name and the
-viewer stacks them on the thread's track.
+A span is a ``jax.profiler.TraceAnnotation``: a host event in the
+profiler's own trace (``<dir>/plugins/profile/<run>/<host>.xplane.pb``),
+on the same clock as the device ops, nested by the profiler under the
+span open on the thread, with the span's attributes as the event's
+stats. This module keeps no clock, event buffer or exporter of its own:
+there is one trace system, the profiler's. ``jax.profiler`` is imported
+only when a span is made, so importing :mod:`repro.obs` imports no JAX.
 
 Enabling:
 
-* ``REPRO_TRACE=1`` (or any truthy value) at import, or
-  ``REPRO_TRACE=/path/out.json`` to also set the default export path;
-* :func:`tracing` as a context manager (exports on exit when given a
-  path);
+* ``with tracing(log_dir):`` turns spans on and records a profiler
+  trace into ``log_dir`` (host spans and device ops, one clock);
+  ``with tracing():`` turns spans on for a profiler session started
+  elsewhere (``jax.profiler.start_trace``, a TensorBoard capture);
+* ``REPRO_TRACE=1`` at import: spans on, for a session started
+  elsewhere; ``REPRO_TRACE=<dir>``: spans on, and a profiler trace into
+  ``<dir>`` that starts with the first span and stops at exit;
 * :func:`enable` / :func:`disable` imperatively.
 
 Overhead policy (DESIGN.md §15): when disabled, :func:`span` returns
@@ -23,30 +27,24 @@ that work with :func:`enabled` themselves; the tracer cannot un-pay
 work done before the call.
 
 A note on jit: spans emitted inside a ``jax.jit``-ed function body run
-at **trace time** — once per compilation, not per call. That is the
-"one span per plan lowering" semantic the engine uses deliberately:
-the jitted kernel bodies emit lowering spans, while the un-jitted
-dispatchers (``run_window_plan``/``run_scan_plan``) emit per-call
-spans.
+at **trace time** — once per compilation, not per call. So
+``engine.lower`` marks one plan lowering on the host timeline, while
+the un-jitted dispatchers (``run_window_plan``/``run_scan_plan``) emit
+a span per eager call. What runs on the device is named by the
+program itself, not by spans: kernel names and ``jax.named_scope``
+layers (:mod:`repro.obs.scopes`).
 """
 from __future__ import annotations
 
+import atexit
 import functools
-import json
 import os
-import threading
-import time
 
 TRACE_ENV = "REPRO_TRACE"
 
 _enabled = False
-_default_path: str | None = None
-_events: list[dict] = []
-_lock = threading.Lock()
-_tls = threading.local()
-# Trace timestamps are µs relative to this origin (Chrome trace wants
-# monotonically comparable ts, not epoch time).
-_T0 = time.perf_counter()
+# REPRO_TRACE=<dir>: the profiler session to start with the first span.
+_pending_dir: str | None = None
 
 
 class _NullSpan:
@@ -68,12 +66,10 @@ def enabled() -> bool:
     return _enabled
 
 
-def enable(path: str | None = None) -> None:
-    """Turn span collection on (``path`` sets the default export file)."""
-    global _enabled, _default_path
+def enable() -> None:
+    """Turn spans on (they reach whichever profiler session is open)."""
+    global _enabled
     _enabled = True
-    if path:
-        _default_path = path
 
 
 def disable() -> None:
@@ -81,89 +77,54 @@ def disable() -> None:
     _enabled = False
 
 
-def clear() -> None:
-    with _lock:
-        _events.clear()
+def _profile_options():
+    """Host spans and device ops, without the Python function tracer."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return opts
 
 
-def events() -> list[dict]:
-    """A copy of the collected Chrome-trace events."""
-    with _lock:
-        return list(_events)
+def _start_pending_session() -> None:
+    global _pending_dir
+    log_dir, _pending_dir = _pending_dir, None
+    import jax
+
+    jax.profiler.start_trace(log_dir, profiler_options=_profile_options())
+    atexit.register(jax.profiler.stop_trace)
 
 
-def _stack() -> list["_Span"]:
-    st = getattr(_tls, "stack", None)
-    if st is None:
-        st = _tls.stack = []
-    return st
+def _annotation(name: str, cat: str, attrs: dict):
+    if _pending_dir is not None:
+        _start_pending_session()
+    from jax.profiler import TraceAnnotation
 
-
-def current_stack() -> tuple[str, ...]:
-    """Names of the open spans on this thread, outermost first."""
-    return tuple(s.name for s in _stack())
-
-
-def annotate(**attrs) -> None:
-    """Attach attributes to the innermost open span on this thread.
-
-    The guarded dispatcher uses this to stamp demotions onto whatever
-    engine/op span is already open, without threading span objects
-    through the lattice. No-op when tracing is disabled or no span is
-    open — same one-bool-read discipline as :func:`span`.
-    """
-    if not _enabled:
-        return
-    st = _stack()
-    if st:
-        st[-1].attrs.update(attrs)
-
-
-class _Span:
-    __slots__ = ("name", "cat", "attrs", "_t0")
-
-    def __init__(self, name: str, cat: str, attrs: dict):
-        self.name = name
-        self.cat = cat
-        self.attrs = attrs
-
-    def __enter__(self):
-        st = _stack()
-        if st:
-            self.attrs.setdefault("parent", st[-1].name)
-        st.append(self)
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        st = _stack()
-        if st and st[-1] is self:
-            st.pop()
-        self.attrs["depth"] = len(st)
-        with _lock:
-            _events.append({
-                "name": self.name,
-                "cat": self.cat,
-                "ph": "X",
-                "ts": (self._t0 - _T0) * 1e6,
-                "dur": (t1 - self._t0) * 1e6,
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": self.attrs,
-            })
-        return False
+    return TraceAnnotation(name, cat=cat, **attrs)
 
 
 def span(name: str, cat: str = "repro", **attrs):
     """A span context manager — or the shared no-op when disabled.
 
-    Attribute values must be JSON-serializable (stringify plans and
-    dtypes at the call site, and only when :func:`enabled`).
+    Attribute values are ints, floats or strings (anything else is
+    stored as its ``str``).
     """
     if not _enabled:
         return NULL
-    return _Span(name, cat, attrs)
+    return _annotation(name, cat, attrs)
+
+
+def annotate(**attrs) -> None:
+    """Record attributes where the thread is now: a short ``annotate``
+    event carrying them, which the profiler nests inside whatever span
+    is open (the guarded dispatcher stamps demotions this way). No-op
+    when tracing is disabled — one boolean read, as :func:`span`.
+    """
+    if not _enabled:
+        return
+    with _annotation("annotate", "repro", attrs):
+        pass
 
 
 def traced(name: str | None = None, cat: str = "repro"):
@@ -176,7 +137,7 @@ def traced(name: str | None = None, cat: str = "repro"):
         def wrapper(*args, **kwargs):
             if not _enabled:
                 return fn(*args, **kwargs)
-            with _Span(label, cat, {}):
+            with _annotation(label, cat, {}):
                 return fn(*args, **kwargs)
 
         return wrapper
@@ -185,50 +146,41 @@ def traced(name: str | None = None, cat: str = "repro"):
 
 
 class tracing:
-    """``with obs.tracing("out.json"): ...`` — enable, run, export.
+    """``with obs.tracing(log_dir): ...`` — spans on, and a profiler
+    trace of the block written under ``log_dir``.
 
-    Restores the previous enabled state on exit, so nested/tested use
-    cannot leak tracing into the rest of the process.
+    Without ``log_dir`` only the spans are turned on, for a profiler
+    session started elsewhere. Restores the previous enabled state on
+    exit, so nested or tested use cannot leak tracing into the rest of
+    the process.
     """
 
-    def __init__(self, path: str | None = None, *, fresh: bool = True):
-        self.path = path
-        self.fresh = fresh
+    def __init__(self, log_dir: str | os.PathLike | None = None):
+        self.log_dir = log_dir
         self._was = False
 
     def __enter__(self):
         self._was = _enabled
-        if self.fresh:
-            clear()
-        enable(self.path)
+        if self.log_dir is not None:
+            import jax
+
+            jax.profiler.start_trace(str(self.log_dir),
+                                     profiler_options=_profile_options())
+        enable()
         return self
 
     def __exit__(self, *exc):
-        if self.path:
-            export(self.path)
         if not self._was:
             disable()
+        if self.log_dir is not None:
+            import jax
+
+            jax.profiler.stop_trace()
         return False
-
-
-def export(path: str | None = None) -> str | None:
-    """Write the collected events as Chrome-trace JSON; returns the path.
-
-    The document shape is the Chrome Trace Event Format's object form:
-    ``{"traceEvents": [...], "displayTimeUnit": "ms"}`` — what
-    ``chrome://tracing`` and Perfetto ingest unmodified.
-    """
-    path = path or _default_path
-    if not path:
-        return None
-    doc = {"traceEvents": events(), "displayTimeUnit": "ms"}
-    d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=1)
-    return path
 
 
 _env = os.environ.get(TRACE_ENV, "")
 if _env and _env.lower() not in ("0", "false", "off"):
-    enable(None if _env.lower() in ("1", "true", "on") else _env)
+    enable()
+    if _env.lower() not in ("1", "true", "on"):
+        _pending_dir = _env

@@ -1,20 +1,22 @@
 """Observability layer (DESIGN.md §15): tracer, metrics, drift.
 
-Covers the PR-9 acceptance gates:
-- disabled-mode fast path: ``span()`` returns the shared no-op, the
-  event buffer stays empty, and the per-span overhead is bounded;
-- span nesting + Chrome-trace JSON validity (``ph: "X"`` complete
-  events with µs timestamps, parent attribution, valid ``json.dumps``);
-- engine counters match known launch counts (per-call launches vs
-  per-compile lowerings);
+Covers:
+- disabled-mode fast path: ``span()`` returns the shared no-op, a
+  profiler session records no span, and the per-span overhead is
+  bounded;
+- spans on the profiler's clock: ``obs.tracing(dir)`` writes a
+  ``jax.profiler`` trace whose host plane holds the spans, nested, with
+  their attributes as stats;
+- engine counters match known launch counts (eager launches vs
+  per-compile lowerings; a jitted program launches nothing itself);
 - drift recorder math (geomean ratios, backend pooling, worst-cell
   ranking) and the report CLI;
 - serve latency histograms (p50/p99 in ``metrics.snapshot()``);
 - ``measure_us`` spread + ``$REPRO_MEASURE_REPS`` and the v7 sidecar
   schema (spread persisted, stale v6 entries dropped on load).
 """
+import glob
 import json
-import math
 import time
 
 import jax
@@ -31,14 +33,28 @@ from repro.kernels import ops
 def _clean_telemetry():
     """Every test starts (and leaves) with telemetry off and empty."""
     obs.trace.disable()
-    obs.trace.clear()
     obs.metrics.reset()
     obs.drift.reset()
     yield
     obs.trace.disable()
-    obs.trace.clear()
     obs.metrics.reset()
     obs.drift.reset()
+
+
+def _host_events(log_dir) -> dict:
+    """``{name: [(start_ns, end_ns, stats), ...]}`` of the host events of
+    the profiler trace written under ``log_dir``."""
+    (path,) = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                s = int(ev.start_ns)
+                out.setdefault(ev.name, []).append(
+                    (s, s + int(ev.duration_ns), dict(ev.stats)))
+    return out
 
 
 class TestTracerDisabled:
@@ -46,11 +62,17 @@ class TestTracerDisabled:
         assert obs.span("anything", key="val") is obs.trace.NULL
         assert obs.span("other") is obs.trace.NULL
 
-    def test_no_events_collected(self):
-        with obs.span("a"):
-            with obs.span("b"):
-                pass
-        assert obs.trace.events() == []
+    def test_no_events_collected(self, tmp_path):
+        """A profiler session open while spans are off records none."""
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("a"):
+                with obs.span("b"):
+                    obs.trace.annotate(k=1)
+        finally:
+            jax.profiler.stop_trace()
+        evs = _host_events(tmp_path)
+        assert not {"a", "b", "annotate"} & set(evs)
 
     def test_disabled_overhead_bounded(self):
         """The no-op path is a function call + a bool read — bound it
@@ -63,10 +85,13 @@ class TestTracerDisabled:
                 pass
         per_span = (time.perf_counter() - t0) / n
         assert per_span < 100e-6, f"{per_span * 1e6:.2f} µs per no-op span"
-        assert obs.trace.events() == []
 
-    def test_traced_decorator_passthrough(self):
+    def test_traced_decorator_passthrough(self, monkeypatch):
         calls = []
+
+        def no_annotation(*a):
+            raise AssertionError("a disabled span made a TraceAnnotation")
+        monkeypatch.setattr(obs.trace, "_annotation", no_annotation)
 
         @obs.trace.traced("deco")
         def fn(v):
@@ -75,45 +100,102 @@ class TestTracerDisabled:
 
         assert fn(1) == 2
         assert calls == [1]
-        assert obs.trace.events() == []
 
 
 class TestTracerEnabled:
-    def test_nesting_and_parent_attribution(self):
-        with obs.tracing():
+    def test_nesting_and_parent_attribution(self, tmp_path):
+        """The profiler nests spans by time on the thread's line."""
+        with obs.tracing(tmp_path):
             with obs.span("outer"):
-                assert obs.trace.current_stack() == ("outer",)
                 with obs.span("inner"):
-                    assert obs.trace.current_stack() == ("outer", "inner")
-        evs = {e["name"]: e for e in obs.trace.events()}
-        assert set(evs) == {"outer", "inner"}
-        assert evs["inner"]["args"]["parent"] == "outer"
-        assert evs["inner"]["args"]["depth"] == 1
-        assert evs["outer"]["args"]["depth"] == 0
-        # inner completes within outer's interval
-        assert evs["inner"]["ts"] >= evs["outer"]["ts"]
-        assert (evs["inner"]["ts"] + evs["inner"]["dur"]
-                <= evs["outer"]["ts"] + evs["outer"]["dur"] + 1e-3)
+                    obs.trace.annotate(demoted="a->b")
+        evs = _host_events(tmp_path)
+        ((o0, o1, _),) = evs["outer"]
+        ((i0, i1, _),) = evs["inner"]
+        ((a0, a1, stats),) = evs["annotate"]
+        assert o0 <= i0 <= a0 <= a1 <= i1 <= o1
+        assert stats["demoted"] == "a->b"
 
     def test_chrome_trace_export_shape(self, tmp_path):
-        path = tmp_path / "trace.json"
-        with obs.tracing(str(path)):
-            with obs.span("work", cat="test", detail="x"):
+        """``obs.tracing(dir)`` writes the profiler's own trace (the
+        ``.xplane.pb`` TensorBoard, Perfetto and ``bench/trace.py``
+        read); a span's category and attributes are its event's stats."""
+        with obs.tracing(tmp_path):
+            with obs.span("work", cat="test", detail="x", n=3):
                 pass
-        doc = json.loads(path.read_text())
-        assert doc["displayTimeUnit"] == "ms"
-        (ev,) = doc["traceEvents"]
-        assert ev["ph"] == "X"
-        assert ev["name"] == "work" and ev["cat"] == "test"
-        assert isinstance(ev["ts"], float) and isinstance(ev["dur"], float)
-        assert ev["dur"] >= 0
-        assert {"pid", "tid", "args"} <= set(ev)
+        assert not list(tmp_path.glob("*.json"))
+        ((s, e, stats),) = _host_events(tmp_path)["work"]
+        assert e >= s
+        assert stats == {"cat": "test", "detail": "x", "n": 3}
+
+    def test_env_dir_records_a_profile(self, tmp_path):
+        """``REPRO_TRACE=<dir>``: a profiler trace into ``dir`` from the
+        first span to the end of the process."""
+        import os
+        import subprocess
+        import sys
+
+        code = ("import jax.numpy as jnp\n"
+                "from repro.kernels import ops\n"
+                "ops.cumsum(jnp.ones((8, 256)), impl='interpret')\n")
+        src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+        env = dict(os.environ, REPRO_TRACE=str(tmp_path), JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       [src, os.environ.get("PYTHONPATH", "")]))
+        subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                       timeout=120)
+        evs = _host_events(tmp_path)
+        assert "engine.run_scan_plan" in evs and "engine.lower" in evs
 
     def test_tracing_restores_prior_state(self):
         assert not obs.trace.enabled()
         with obs.tracing():
             assert obs.trace.enabled()
         assert not obs.trace.enabled()
+
+
+HLO = """HloModule m
+
+%fused (p: f32[16]) -> f32[16] {
+  %p = f32[16]{0} parameter(0)
+  ROOT %neg = f32[16]{0} negate(%p), metadata={op_name="jit(f)/halo.frame/neg"}
+}
+
+ENTRY %main (x.1: f32[8]) -> f32[16] {
+  %x.1 = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %c = f32[] constant(0), metadata={op_name="jit(f)"}
+  %copy-start = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(%x.1)
+  %copy-done = f32[8]{0:S(1)} copy-done(%copy-start)
+  %pad.2 = f32[16]{0} pad(%copy-done, %c), padding=0_8, metadata={op_name="jit(f)/halo.interior/jit(g)/engine.pad/pad"}
+  %fusion.3 = f32[16]{0} fusion(%pad.2), kind=kLoop, calls=%fused, metadata={op_name="jit(f)/halo.exchange/concatenate" stack_frame_id=3}
+  %copy-start.1 = (f32[16]{0}, f32[16]{0}, u32[]{:S(2)}) copy-start(%fusion.3)
+  ROOT %copy-done.1 = f32[16]{0} copy-done(%copy-start.1)
+}
+"""
+
+
+class TestScopes:
+    def test_instruction_layers(self):
+        """Own op_names as written; an inserted copy takes its operand's
+        path, or its user's where the operand is a parameter."""
+        paths = obs.scopes.instruction_layers(HLO)
+        pad = "jit(f)/halo.interior/jit(g)/engine.pad/pad"
+        concat = "jit(f)/halo.exchange/concatenate"
+        assert paths == {
+            "neg": "jit(f)/halo.frame/neg", "x.1": "x", "c": "jit(f)",
+            "copy-start": pad, "copy-done": pad, "pad.2": pad,
+            "fusion.3": concat, "copy-start.1": concat,
+            "copy-done.1": concat}
+
+    def test_layer_of_innermost_scope(self):
+        path = "jit(f)/halo.interior/jit(g)/engine.pad/pad"
+        assert obs.scopes.layer_of(path, "engine.") == "engine.pad"
+        assert obs.scopes.layer_of(path, "halo.") == "halo.interior"
+        assert obs.scopes.layer_of(
+            "jit(f)/transpose(jvp(engine.crop))/slice",
+            "engine.") == "engine.crop"
+        assert obs.scopes.layer_of("x", "engine.") is None
+        assert obs.scopes.layer_of(None, "halo.") is None
 
 
 class TestMetrics:
@@ -170,26 +252,35 @@ class TestEngineCounters:
         delta = c["tpu:scan"] - before.get("tpu:scan", 0)
         assert delta == 1, f"expected 1 compile, counted {delta}"
 
-    def test_engine_spans_when_tracing(self):
-        x = jnp.ones((8, 384), jnp.float32)
-        with obs.tracing():
-            ops.cumsum(x, impl="interpret")
-            ops.cumsum(x, impl="interpret")
-        names = [e["name"] for e in obs.trace.events()]
-        assert names.count("engine.run_scan_plan") == 2   # per call
-        assert names.count("engine.lower") == 1           # per compile
-        (low,) = [e for e in obs.trace.events()
-                  if e["name"] == "engine.lower"]
-        assert low["args"]["backend"] == "tpu"
-        assert low["args"]["plan"].startswith("scan-")
-        assert low["args"]["model_cost"] > 0
+    def test_jitted_calls_launch_nothing(self):
+        """Under jit the dispatcher runs at trace time: one lowering, and
+        no launch counted for the five calls of the compiled program."""
+        x = jnp.ones((8, 448), jnp.float32)      # unique shape → fresh compile
+        f = jax.jit(lambda v: ops.cumsum(v, impl="interpret"))
+        for _ in range(5):
+            f(x).block_until_ready()
+        assert obs.metrics.counter_total("engine.launch") == 0
+        assert obs.metrics.counter_total("engine.lowering") == 1
 
-    def test_backward_spans_when_tracing(self):
+    def test_engine_spans_when_tracing(self, tmp_path):
+        x = jnp.ones((8, 384), jnp.float32)
+        with obs.tracing(tmp_path):
+            ops.cumsum(x, impl="interpret")
+            ops.cumsum(x, impl="interpret")
+        evs = _host_events(tmp_path)
+        runs = sorted(evs["engine.run_scan_plan"])
+        assert len(runs) == 2                             # per call
+        ((l0, l1, low),) = evs["engine.lower"]            # per compile
+        assert runs[0][0] <= l0 <= l1 <= runs[0][1]       # nested in call 1
+        assert low["cat"] == "engine" and low["backend"] == "tpu"
+        assert low["plan"].startswith("scan-")
+        assert low["model_cost"] > 0
+
+    def test_backward_spans_when_tracing(self, tmp_path):
         x = jnp.ones((8, 256), jnp.float32)
-        with obs.tracing():
+        with obs.tracing(tmp_path):
             jax.grad(lambda v: ops.cumsum(v, impl="interpret").sum())(x)
-        names = {e["name"] for e in obs.trace.events()}
-        assert "ops.cumsum_bwd" in names
+        assert "ops.cumsum_bwd" in _host_events(tmp_path)
 
 
 class TestDrift:
