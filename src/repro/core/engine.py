@@ -53,20 +53,25 @@ from .plan import (EPILOGUE_OPERANDS, EpilogueStage, SystolicPlan, Tap,
 
 
 def _obs_lowering(*, plan: SystolicPlan, block, backend: str,
-                  time_steps: int = 1, variant: str = "shift_psum"):
+                  time_steps: int = 1, variant: str = "shift_psum",
+                  derived: bool = False):
     """Trace-time telemetry for one plan lowering (both backends call it).
 
     This runs inside the ``jax.jit``-ed lowering bodies, so its Python
     side effects fire once per *compilation*, not per call: the
     ``engine.lowering`` counter is the lowering-cache-miss (recompile)
-    count, and the returned span — the "one span per plan lowering"
-    event — times the trace+lower work itself, carrying the plan
-    signature, strategy, block and the §5 predicted cost. Disabled
-    tracing pays one counter bump and one boolean check.
+    count, ``engine.block`` says whether the block was ``auto`` (sized
+    by :func:`default_window_block`) or ``given`` (by the caller, the
+    tuner or a family default), and the returned span — the "one span
+    per plan lowering" event — times the trace+lower work itself,
+    carrying the plan signature, strategy, resolved block and the §5
+    predicted cost. Disabled tracing pays two counter bumps and one
+    boolean check.
     """
     strategy = (plan.strategy or "lanes") if plan.combine == "fma" \
         else plan.combine
     obs.metrics.inc("engine.lowering", f"{backend}:{plan.kind}")
+    obs.metrics.inc("engine.block", "auto" if derived else "given")
     if not obs.trace.enabled():
         return obs.trace.NULL
     from . import tuning
@@ -426,6 +431,155 @@ def _round_block(shape, out_block, grid, tile) -> tuple[int, ...]:
         for n, b, g, t in zip(shape[-2:], out_block[-2:], grid[-2:], tile))
 
 
+# ---------------------------------------------------------------------------
+# The output block of a TPU-lowered 2-D windowed plan (DESIGN.md §17)
+# ---------------------------------------------------------------------------
+
+#: The block of a 2-D windowed plan the rule does not size: GPU lowerings,
+#: 'mxu' and output-strided plans, and no candidate fitting.
+FAMILY_BLOCK_2D = (8, 128)
+#: Scoped VMEM one Mosaic kernel may hold on a v5e by default (its
+#: RESOURCE_EXHAUSTED message: "limit 16.00M"). The estimate stays under
+#: VMEM_HEADROOM of it: the temporaries term is fitted, not derived.
+SCOPED_VMEM_BYTES = 16 << 20
+VMEM_HEADROOM = 0.85
+#: Fixed cost of one grid step, µs: a v5e ran 16384² 2d5pt (t=1) at an
+#: 8×128 block in 85.1 ms a call over 262,144 steps (PERF.md §6), which
+#: is almost all per-step cost.
+STEP_US = 0.325
+#: v5e HBM bandwidth in bytes per µs (819 GB/s, bench/peaks.json).
+HBM_BYTES_PER_US = 819e3
+#: Mosaic unrolls the kernel body over the block's vregs, so its compile
+#: time grows with input-block vregs × taps × fused applications: for a
+#: described v5e, 5,940 (2d5pt, t=4, 256×1024) took about 6 s and
+#: 135,762 (2d121pt, 512×2048) over 3 minutes. Blocks stay under this.
+UNROLL_BUDGET = 8192
+_BLOCK_ROWS = tuple(8 << k for k in range(10))       # 8 … 4096
+_BLOCK_LANES = tuple(128 << k for k in range(6))     # 128 … 4096
+
+
+def _derives_block(plan: SystolicPlan) -> bool:
+    """Whether the TPU lowering sizes ``plan``'s output block with
+    :func:`default_window_block` when the caller gives none: stride-1
+    plans with two windowed axes on the 'lanes' strategy (every stage of
+    a fused chain included)."""
+    return (plan.combine == "fma" and plan.ndim_spatial == 2
+            and all(v == 1 for v in plan.stride_per_axis())
+            and all((s.strategy or plan.strategy or "lanes") == "lanes"
+                    for s in plan.stages or (plan,)))
+
+
+def _tiled(shape, tile) -> int:
+    """Elements a block occupies laid out in ``tile``s (VMEM and HBM pad
+    the last two dims to the tile)."""
+    return (-(-shape[-2] // tile[0]) * tile[0]
+            * -(-shape[-1] // tile[1]) * tile[1])
+
+
+def _vmem_temporaries(plan: SystolicPlan, time_steps: int,
+                      variant: str) -> int:
+    """Live kernel temporaries, in f32 input blocks, fitted to what the
+    TPU compiler refused for a described v5e at 16 MiB (DESIGN.md §17).
+
+    One application reads its taps straight from the input buffer; only
+    the partial sum is live (plus one rolled copy of the data per column
+    shift under ``shift_data``). Fused applications (``time_steps > 1``
+    or a chain) take their taps from a computed block, and every
+    unaligned row offset and lane shift of a stage's footprint holds a
+    copy of it: 2d5pt needs 3.5–5.5 blocks, 2d9pt and 2d25pt over 7.
+    """
+    if time_steps == 1 and not plan.stages:
+        return 1 + (plan.shift_count() if variant == "shift_data" else 0)
+    return max(s.N + s.M - 1 for s in plan.stages or (plan,))
+
+
+def window_block_cost(plan: SystolicPlan, block: tuple[int, int],
+                      out_shape: tuple[int, int], dtype, time_steps: int = 1,
+                      variant: str = "shift_psum",
+                      acc_dtype=jnp.float32) -> tuple[int, int, float]:
+    """What :func:`default_window_block` estimates for one output block
+    of a call with spatial output ``out_shape``: ``(VMEM bytes, unrolled
+    vreg-taps, µs)``.
+
+    VMEM: double-buffered input and output blocks (output-shaped
+    epilogue operands and per-lane coefficient rows too), the reduce
+    accumulator and the kernel temporaries (:func:`_vmem_temporaries`),
+    each padded to its tile. Unrolled size: the input block's f32 vregs
+    × taps × fused applications. Time: grid steps × ``STEP_US`` plus the
+    bytes read and written — the input block's halo and tile round-up
+    and the output's overhang included — over ``HBM_BYTES_PER_US``.
+    """
+    tile = tpu_tile(dtype)
+    item = jnp.dtype(dtype).itemsize
+    acc = jnp.dtype(acc_dtype).itemsize
+    B = tuple(min(b, o) for b, o in zip(block, out_shape))
+    g = tuple(pl.cdiv(o, b) for o, b in zip(out_shape, B))
+    in_block = _round_block(plan.block_in_shape(B, time_steps), B, g, tile)
+    in_el, out_el = _tiled(in_block, tile), _tiled(B, tile)
+    f32_in = _tiled(in_block, (8, 128))
+    out_like = 1 + sum(s.op == "residual_add"
+                       for s in chain_epilogue_operand_stages(plan))
+    vmem = (2 * (in_el + out_like * out_el) * item
+            + (_vmem_temporaries(plan, time_steps, variant) * f32_in
+               + (out_el if plan.reduce_axes else 0)) * acc)
+    if plan.coeff_mode == "perlane":
+        vmem += 2 * _tiled((plan.N, B[1]), tile) * item
+    unroll = f32_in // 1024 * plan.mads_per_output_window() * time_steps
+    us = g[0] * g[1] * (STEP_US + (in_el + out_el) * item / HBM_BYTES_PER_US)
+    return vmem, unroll, us
+
+
+def default_window_block(plan: SystolicPlan, out_shape: tuple[int, int],
+                         dtype, time_steps: int = 1,
+                         variant: str = "shift_psum",
+                         acc_dtype=jnp.float32) -> tuple[int, int]:
+    """The output block of a TPU-lowered 2-D windowed call, from what the
+    call can observe: its output extent, dtype, the plan's halo at
+    ``time_steps`` and the VMEM the kernel will hold (DESIGN.md §17).
+
+    Candidate rows are sublane-tile multiples (8 … 4096), lanes 128 …
+    4096, both clamped to the output as :func:`_window_call` clamps. A
+    candidate qualifies when its VMEM estimate stays under
+    ``VMEM_HEADROOM × SCOPED_VMEM_BYTES`` and its unrolled body under
+    ``UNROLL_BUDGET``; the rule picks the qualifying block of least
+    estimated time (:func:`window_block_cost`). With none left it
+    returns the family block, clamped.
+    """
+    rows = sorted({min(r, out_shape[0]) for r in _BLOCK_ROWS
+                   if r % tpu_tile(dtype)[0] == 0})
+    lanes = sorted({min(c, out_shape[1]) for c in _BLOCK_LANES})
+    budget = VMEM_HEADROOM * SCOPED_VMEM_BYTES
+    best = None
+    for B in ((bh, bw) for bh in rows for bw in lanes):
+        vmem, unroll, us = window_block_cost(plan, B, out_shape, dtype,
+                                             time_steps, variant, acc_dtype)
+        if vmem <= budget and unroll <= UNROLL_BUDGET and (
+                best is None or us < best[0]):
+            best = (us, B)
+    if best is None:
+        return tuple(min(b, o) for b, o in zip(FAMILY_BLOCK_2D, out_shape))
+    return best[1]
+
+
+def window_block(plan: SystolicPlan, block, in_shape, dtype,
+                 time_steps: int, variant: str, backend: str,
+                 acc_dtype=jnp.float32) -> tuple[tuple[int, ...], bool]:
+    """``(block, derived)`` for one windowed lowering: a given ``block``
+    as it is; ``None`` (no block given) sized by
+    :func:`default_window_block` from the call's spatial ``in_shape``
+    where :func:`_derives_block` holds on the TPU, else
+    ``FAMILY_BLOCK_2D``."""
+    if block is not None:
+        return tuple(block), False
+    assert plan.ndim_spatial == 2, (
+        "only 2-D windowed plans take a derived block", plan.kind)
+    if backend != "tpu" or not _derives_block(plan):
+        return FAMILY_BLOCK_2D, False
+    out = plan.out_shape(tuple(in_shape), time_steps)
+    return default_window_block(plan, out, dtype, time_steps, variant,
+                                acc_dtype), True
+
+
 def _window_call(
     x: jax.Array,
     w,
@@ -623,7 +777,7 @@ def _run_window_plan_tpu(
     w=None,
     *,
     plan: SystolicPlan,
-    block: tuple[int, ...],
+    block: tuple[int, ...] | None,
     time_steps: int = 1,
     variant: str = "shift_psum",
     interpret: bool = True,
@@ -631,8 +785,9 @@ def _run_window_plan_tpu(
     epilogue_args: tuple = (),
     strategy: str | None = None,
 ) -> jax.Array:
-    """The TPU lowering: 8×128 sublane/lane tiles, VPU lane rolls for
-    ``shift_psum``, fp32 VMEM scratch for the reduce accumulator."""
+    """The TPU lowering: sublane/lane-tiled blocks (``block=None``: sized
+    per call by :func:`window_block`), VPU lane rolls for ``shift_psum``,
+    fp32 VMEM scratch for the reduce accumulator."""
     if strategy is not None:
         # kwarg convenience for the thin family wrappers + tuner replay:
         # the strategy still lives on the plan IR (adjoints/fusion
@@ -647,8 +802,12 @@ def _run_window_plan_tpu(
     def make_scratch(B, in_block):
         return [pltpu.VMEM(B, acc_dtype)] if plan.reduce_axes else []
 
+    block, derived = window_block(
+        plan, block, x.shape[plan.batch_axes + plan.reduce_axes:], x.dtype,
+        time_steps, variant, "tpu", acc_dtype)
     with _obs_lowering(plan=plan, block=block, backend="tpu",
-                       time_steps=time_steps, variant=variant):
+                       time_steps=time_steps, variant=variant,
+                       derived=derived):
         return _window_call(
             x, w, plan=plan, block=block, time_steps=time_steps,
             variant=variant, interpret=interpret, acc_dtype=acc_dtype,
@@ -661,7 +820,7 @@ def run_window_plan(
     w=None,
     *,
     plan: SystolicPlan,
-    block: tuple[int, ...],
+    block: tuple[int, ...] | None,
     time_steps: int = 1,
     variant: str = "shift_psum",
     interpret: bool = True,
@@ -681,7 +840,9 @@ def run_window_plan(
         For a fused pipeline (``plan.stages``), a tuple with one entry
         per stage — an array for 'dense' stages, None for 'table' ones.
       plan: the systolic schedule + geometry (lead/trail, footprint).
-      block: output block size per windowed axis, lane axis last.
+      block: output block size per windowed axis, lane axis last; None
+        (2-D plans) lets the lowering size it for this call
+        (:func:`window_block`, DESIGN.md §17).
       time_steps: fused plan applications per block (§6.4).
       epilogue_args: runtime operands of the chain's operand-bearing
         epilogue stages, in application order (mid-chain ``bias``
@@ -730,7 +891,10 @@ def run_window_plan(
         else:
             out = _run_window_plan_tpu(x, w, **kw)
     if eager and obs.drift.per_call():
-        _obs_call_drift(eff, block, backend, time_steps, variant, out, t0,
+        blk, _ = window_block(eff, block, x.shape[-eff.ndim_spatial:],
+                              x.dtype, time_steps, variant, backend,
+                              acc_dtype)
+        _obs_call_drift(eff, blk, backend, time_steps, variant, out, t0,
                         x.shape)
     return out
 

@@ -239,7 +239,7 @@ def _run_window_plan_gpu_jit(
     w=None,
     *,
     plan: SystolicPlan,
-    block: tuple[int, ...],
+    block: tuple[int, ...] | None,
     time_steps: int = 1,
     variant: str = "shift_psum",
     interpret: bool = True,
@@ -270,6 +270,9 @@ def _run_window_plan_gpu_jit(
             scratch.append(plgpu.SMEM(B, acc_dtype))  # register accumulator
         return scratch
 
+    block, _ = engine.window_block(
+        plan, block, x.shape[plan.batch_axes + plan.reduce_axes:], x.dtype,
+        time_steps, variant, "gpu", acc_dtype)
     with engine._obs_lowering(plan=plan, block=block, backend="gpu",
                               time_steps=time_steps, variant=variant):
         return engine._window_call(

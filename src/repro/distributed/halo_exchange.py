@@ -457,7 +457,7 @@ def sharded_window_plan(
     plan: SystolicPlan,
     mesh: Mesh,
     in_spec: P | None = None,
-    block: tuple[int, ...],
+    block: tuple[int, ...] | None,
     time_steps: int = 1,
     variant: str = "shift_psum",
     boundary: str = "zero",
@@ -484,7 +484,8 @@ def sharded_window_plan(
         domain) to mesh axes; at most one mesh axis per axis. Defaults
         to the rule-table resolution of :func:`default_plan_spec`.
       block / time_steps / variant / interpret / acc_dtype: forwarded to
-        the engine, per shard.
+        the engine, per shard; ``block=None`` lets each engine call (the
+        interior and every frame strip) size its own block.
       boundary: 'zero' (the engine's semantics — domain-edge shards
         receive the origin padding from the collective itself), 'wrap'
         (torus), or 'replicate' (edge clamp; ``time_steps == 1`` only,

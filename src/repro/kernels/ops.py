@@ -107,6 +107,10 @@ def _interp(impl: str) -> bool:
     return impl == "interpret"
 
 
+# Family blocks: the tuner's starting point, the §16.3 lattice's
+# 'default' level and the other half of a half-given block. A 2-D call
+# given no block at all leaves it to the TPU lowering, which sizes it
+# from the call's shape (_engine_block, DESIGN.md §17).
 _DEFAULTS = {
     "conv2d": tuning.KernelConfig((8, 128)),
     "conv2d_nchw": tuning.KernelConfig((8, 128)),
@@ -154,8 +158,14 @@ def _strategy_plan(plan, strategy, op: str):
     return dataclasses.replace(plan, strategy=strategy)
 
 
-def _engine_block(plan, kw: dict) -> tuple[tuple[int, ...], str, dict]:
-    """Split family kwargs into (engine block tuple, variant, rest)."""
+def _engine_block(plan, kw: dict) -> tuple[tuple[int, ...] | None, str, dict]:
+    """Split family kwargs into (engine block tuple, variant, rest).
+
+    A 2-D plan given neither ``block_h`` nor ``block_w`` gets ``None``:
+    the TPU lowering sizes its block from each call's own shape
+    (:func:`repro.core.engine.window_block`). A half-given block takes
+    the other half from the family default.
+    """
     kw = dict(kw)
     d = _default_cfg(plan).block
     if plan.kind == "conv1d":
@@ -163,8 +173,10 @@ def _engine_block(plan, kw: dict) -> tuple[tuple[int, ...], str, dict]:
     elif plan.ndim_spatial == 3:
         block = (kw.pop("block_z", d[0]), kw.pop("block_h", d[1]),
                  kw.pop("block_w", d[2]))
-    else:
+    elif "block_h" in kw or "block_w" in kw:
         block = (kw.pop("block_h", d[0]), kw.pop("block_w", d[1]))
+    else:
+        block = None
     return block, kw.pop("variant", "shift_psum"), kw
 
 
@@ -298,7 +310,7 @@ class _WindowCfg:
     """Static (nondiff) configuration of one windowed engine call."""
 
     plan: SystolicPlan
-    block: tuple[int, ...]
+    block: tuple[int, ...] | None    # None: sized per call by the lowering
     time_steps: int = 1
     variant: str = "shift_psum"
     interpret: bool = True
@@ -421,7 +433,7 @@ def _window_op_bwd(cfg, res, g):
     if w is None or plan.coeff_mode == "table":
         return dx, None, depi
     adj.record_lowering(adj.weight_adjoint_plan(plan).kind)
-    wg_block = cfg.block[-2:]
+    wg_block = _wgrad_block(cfg)
     if cfg.mesh is not None:
         from repro.distributed import halo_exchange as hx
         dw = hx.sharded_weight_grad(
@@ -433,6 +445,12 @@ def _window_op_bwd(cfg, res, g):
             x, g, plan=plan, block=wg_block, interpret=cfg.interpret,
             acc_dtype=cfg.acc_dtype)
     return dx, dw.astype(w.dtype), depi
+
+
+def _wgrad_block(cfg: _WindowCfg) -> tuple[int, int]:
+    """The weight-gradient tile: the forward's block, or the family
+    default where the forward's block is sized per call."""
+    return (cfg.block or _default_cfg(cfg.plan).block)[-2:]
 
 
 def _replicate_bwd(cfg, plan, x, w, g, depi):
@@ -465,7 +483,7 @@ def _replicate_bwd(cfg, plan, x, w, g, depi):
     adj.record_lowering(adj.weight_adjoint_plan(plan).kind)
     dw = hx.sharded_weight_grad(
         x, g, plan=plan, mesh=cfg.mesh, in_spec=cfg.in_specs,
-        block=cfg.block[-2:], boundary=cfg.boundary,
+        block=_wgrad_block(cfg), boundary=cfg.boundary,
         interpret=cfg.interpret, acc_dtype=cfg.acc_dtype)
     return dx, dw.astype(w.dtype), depi
 
@@ -529,7 +547,7 @@ def _pipeline_bwd(cfg, x, ws, epi, g):
         if s.coeff_mode == "dense":
             adj.record_lowering("wgrad_" + sv.kind)
             dws[i] = run_weight_grad_plan(
-                hs[i], g, plan=sv, block=cfg.block[-2:],
+                hs[i], g, plan=sv, block=_wgrad_block(cfg),
                 interpret=cfg.interpret,
                 acc_dtype=cfg.acc_dtype).astype(ws[i].dtype)
         ap = adj.input_adjoint_plan(sv)     # valid ⇒ full: output grows back

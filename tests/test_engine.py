@@ -752,3 +752,190 @@ class TestMxuStrategy:
             assert not tuning._SIDECAR
         finally:
             tuning.clear_sidecar()
+
+
+# ---------------------------------------------------------------------------
+# The derived output block of 2-D windowed plans (DESIGN.md §17)
+# ---------------------------------------------------------------------------
+
+def _lowered_blocks(monkeypatch) -> list:
+    """The block each windowed lowering is traced with, in order."""
+    from repro.core import engine
+
+    seen = []
+    real = engine._window_call
+
+    def spy(x, w, *, block, **kw):
+        seen.append(tuple(block))
+        return real(x, w, block=block, **kw)
+
+    monkeypatch.setattr(engine, "_window_call", spy)
+    return seen
+
+
+def _block_delta(before: dict) -> dict:
+    from repro import obs
+
+    now = obs.metrics.counter("engine.block")
+    return {k: now[k] - before.get(k, 0) for k in ("auto", "given")}
+
+
+def _plan2d(name: str):
+    sdef = BENCHMARKS[name]
+    return stencil2d_plan(sdef.offsets, coeffs=sdef.coeffs)
+
+
+class TestDerivedBlock:
+    """``engine.default_window_block`` and where it engages: untuned 2-D
+    'lanes' stride-1 calls on the TPU lowering; every other block — a
+    caller's, the tuner's, a sidecar's, 3-D, mxu, strided, conv1d, scan
+    and GPU — passes through as it was."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("name,t,out", [
+        ("2d5pt", 1, (16384, 16384)), ("2d5pt", 4, (16384, 16384)),
+        ("2d5pt", 4, (24576, 4)), ("2d5pt", 4, (4, 24576)),
+        ("2d121pt", 2, (8192, 8192)), ("2d25pt", 2, (8192, 8192)),
+        ("2d9pt", 1, (1000, 700)), ("2d5pt", 1, (48, 128))])
+    def test_block_tiles_fit_vmem_and_unroll(self, name, t, out, dtype):
+        from repro.core import engine
+
+        plan = _plan2d(name)
+        block = engine.default_window_block(plan, out, dtype, t)
+        for b, o, k in zip(block, out, engine.tpu_tile(dtype)):
+            assert 1 <= b <= o and (b % k == 0 or b == o), (block, out)
+        vmem, unroll, us = engine.window_block_cost(plan, block, out, dtype,
+                                                    t)
+        assert vmem <= engine.VMEM_HEADROOM * engine.SCOPED_VMEM_BYTES
+        assert unroll <= engine.UNROLL_BUDGET
+        # never estimated slower than the family block it replaces
+        assert us <= engine.window_block_cost(
+            plan, engine.FAMILY_BLOCK_2D, out, dtype, t)[2]
+
+    @pytest.mark.parametrize("out", [(16384, 16384), (24576, 12),
+                                     (512, 4096), (300, 300)])
+    @pytest.mark.parametrize("name", ["2d5pt", "2d9pt", "2d25pt", "2d121pt"])
+    def test_block_shrinks_or_holds_as_time_steps_grow(self, name, out):
+        from repro.core import engine
+
+        plan = _plan2d(name)
+        blocks = [engine.default_window_block(plan, out, jnp.float32, t)
+                  for t in range(1, 9)]
+        areas = [b[0] * b[1] for b in blocks]
+        assert areas == sorted(areas, reverse=True), blocks
+
+    def test_chip_sized_cells_get_large_blocks(self):
+        """The one-chip sweep's calls leave the 8×128 tile: a grid of a
+        few hundred to a few thousand steps, not 262,144."""
+        from repro.core import engine
+
+        plan = _plan2d("2d5pt")
+        for t in (1, 4):
+            bh, bw = engine.default_window_block(plan, (16384, 16384),
+                                                 jnp.float32, t)
+            assert (16384 // bh) * (16384 // bw) <= 4096, (t, bh, bw)
+
+    @pytest.mark.parametrize("kw,want,shape", [
+        ({"block_h": 16, "block_w": 256}, (16, 256), (40, 392)),
+        ({"block_h": 32}, (32, 128), (40, 400)),
+        ({"block_w": 256}, (8, 256), (40, 408)),
+        ({}, None, (40, 416))])
+    def test_given_and_half_given_blocks_pass_through(self, monkeypatch, rng,
+                                                      kw, want, shape):
+        from repro import obs
+        from repro.core import engine
+        from repro.kernels import ops
+
+        seen = _lowered_blocks(monkeypatch)
+        before = dict(obs.metrics.counter("engine.block"))
+        x = jnp.array(rng.standard_normal(shape), jnp.float32)
+        y = ops.stencil(x, "2d5pt", impl="interpret", **kw)
+        derived = engine.default_window_block(_plan2d("2d5pt"), shape,
+                                              jnp.float32, 1)
+        assert seen == [want or derived]
+        assert _block_delta(before) == (
+            {"auto": 0, "given": 1} if want else {"auto": 1, "given": 0})
+        assert_close(y, ref.stencil_iterate(x, BENCHMARKS["2d5pt"], 1))
+
+    @pytest.mark.parametrize("source", ["sidecar", "tuner"])
+    def test_tuned_and_sidecar_blocks_pass_through(self, monkeypatch, rng,
+                                                   source):
+        from repro import obs
+        from repro.kernels import ops
+
+        shape = (24, 264) if source == "sidecar" else (24, 272)
+        plan = _plan2d("2d5pt")
+        tuning.clear_cache()
+        tuning.clear_sidecar()
+        try:
+            if source == "sidecar":
+                key = tuning._sidecar_key(
+                    tuning.plan_signature(plan), shape, 1,
+                    ("stencil", "interpret"), "auto", "tpu")
+                tuning._SIDECAR[key] = (
+                    tuning.KernelConfig((16, 256), "shift_psum"), 1.0, 2.0)
+            seen = _lowered_blocks(monkeypatch)
+            before = dict(obs.metrics.counter("engine.block"))
+            x = jnp.array(rng.standard_normal(shape), jnp.float32)
+            ops.stencil(x, "2d5pt", impl="interpret", autotune=True)
+            won = tuning.autotune(plan, shape, context=("stencil",
+                                                        "interpret"))
+            assert seen and seen[-1] == won.config.block
+            if source == "sidecar":
+                assert won.config.block == (16, 256)
+            assert _block_delta(before)["auto"] == 0
+        finally:
+            tuning.clear_sidecar()
+            tuning.clear_cache()
+
+    @pytest.mark.parametrize("case", ["3d", "mxu", "strided", "conv1d",
+                                      "gpu"])
+    def test_other_plans_keep_their_blocks(self, monkeypatch, rng, case):
+        from repro import obs
+        from repro.kernels import ops
+
+        seen = _lowered_blocks(monkeypatch)
+        before = dict(obs.metrics.counter("engine.block"))
+        x2 = jnp.array(rng.standard_normal((24, 280)), jnp.float32)
+        if case == "3d":
+            x3 = jnp.array(rng.standard_normal((6, 10, 136)), jnp.float32)
+            ops.stencil(x3, "3d7pt", impl="interpret")
+            want = (4, 8, 128)
+        elif case == "mxu":
+            ops.stencil(x2, "2d5pt", strategy="mxu", impl="interpret")
+            want = (8, 128)
+        elif case == "strided":
+            w = jnp.array(rng.standard_normal((3, 3)), jnp.float32)
+            ops.conv2d(x2, w, mode="same", stride=2, impl="interpret")
+            want = (8, 128)
+        elif case == "conv1d":
+            xs = jnp.array(rng.standard_normal((2, 40, 136)), jnp.float32)
+            w = jnp.array(rng.standard_normal((4, 136)), jnp.float32)
+            ops.conv1d_causal(xs, w, impl="interpret")
+            want = (128, 128)
+        else:
+            ops.stencil(x2, "2d5pt", backend="gpu", impl="interpret")
+            want = (8, 128)
+        assert seen == [want]
+        assert _block_delta(before) == {"auto": 0, "given": 1}
+
+    def test_scan_lowering_counts_given(self):
+        from repro import obs
+        from repro.kernels import ops
+
+        before = dict(obs.metrics.counter("engine.block"))
+        ops.cumsum(jnp.ones((8, 896), jnp.float32), impl="interpret")
+        assert _block_delta(before) == {"auto": 0, "given": 1}
+
+    def test_engine_block_counts_once_per_lowering(self, rng):
+        from repro import obs
+        from repro.kernels import ops
+
+        before = dict(obs.metrics.counter("engine.block"))
+        f = jax.jit(lambda v: ops.stencil(v, "2d5pt", time_steps=2,
+                                          impl="interpret"))
+        x = jnp.array(rng.standard_normal((40, 424)), jnp.float32)
+        for _ in range(3):
+            x = f(x)
+        x.block_until_ready()
+        assert _block_delta(before) == {"auto": 1, "given": 0}

@@ -15,6 +15,8 @@ module is imported: only one process may load the TPU library at a
 time, so under several test workers only the worker running this file
 may load it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,9 +75,18 @@ def _nchw_grads(x, w):
 
 
 FIELD = (8192, 8192)
+CHIP_FIELD = (16384, 16384)      # the one-chip benchmark's domain
 CASES = {
+    # the family block, given explicitly: the grid and pad of an 8×128 tile
     "2d5pt_block8x128": (
-        lambda x: ops.stencil(x, "2d5pt", impl="pallas"), [FIELD], 1),
+        lambda x: ops.stencil(x, "2d5pt", impl="pallas",
+                              block_h=8, block_w=128), [FIELD], 1),
+    # no block given: sized per call from the shape (DESIGN.md §17)
+    "2d5pt_derived_16k_t1": (
+        lambda x: ops.stencil(x, "2d5pt", impl="pallas"), [CHIP_FIELD], 1),
+    "2d5pt_derived_16k_t4": (
+        lambda x: ops.stencil(x, "2d5pt", time_steps=4, impl="pallas"),
+        [CHIP_FIELD], 1),
     "2d5pt_block64x512": (
         lambda x: ops.stencil(x, "2d5pt", impl="pallas",
                               block_h=64, block_w=512), [FIELD], 1),
@@ -111,6 +122,38 @@ CASES = {
 def test_compiles_to_pallas_kernel(one_chip, case):
     fn, shapes, kernels = CASES[case]
     assert _kernels(one_chip, fn, *shapes) == kernels
+
+
+def _pad_shapes(text: str) -> list[str]:
+    return re.findall(r"= (f32\[[\d,]+\])\S* pad\(", text)
+
+
+@pytest.mark.parametrize("t", [1, 4])
+def test_engine_pad_keeps_its_shape(one_chip, t):
+    """An explicit 8×128 block pads 8192² to 8200×8320 (1023·8 + 16
+    rows, 63·128 + 256 lanes), as the family default did; the derived
+    block at 16384² pads to the same 16392×16512 an 8×128 tile did."""
+    for kw, shape, want in (
+            ({"block_h": 8, "block_w": 128}, FIELD, "f32[8200,8320]"),
+            ({}, CHIP_FIELD, "f32[16392,16512]")):
+        x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        text = jax.jit(lambda x: ops.stencil(
+            x, "2d5pt", time_steps=t, impl="pallas", **kw)).lower(
+                x).compile().as_text()
+        assert _pad_shapes(text) == [want], (kw, _pad_shapes(text))
+
+
+def test_sharded_derived_block_2x2(mesh2x2):
+    """The four-chip benchmark's call: 49152² over the 2x2 mesh, four
+    fused steps; the interior and each frame strip size their own block
+    and all five kernels compile."""
+    x = jax.ShapeDtypeStruct((49152, 49152), jnp.float32,
+                             sharding=NamedSharding(mesh2x2,
+                                                    P("data", "model")))
+    text = jax.jit(lambda x: ops.stencil(
+        x, "2d5pt", time_steps=4, impl="pallas", mesh=mesh2x2)).lower(
+            x).compile().as_text()
+    assert text.count("tpu_custom_call") == 5
 
 
 # Instructions that hold data or rename it, and run no work of their own.
