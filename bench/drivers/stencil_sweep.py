@@ -3,10 +3,14 @@
 One dispatch is one jitted program of ``calls_per_dispatch`` engine calls
 of ``steps_per_call`` fused steps each (``impl='pallas'``, untuned family
 default blocks, no tuning sidecar). Dispatches chain: each takes the
-field the last one produced, and the host waits only to keep
-``in_flight`` of them queued. A configuration with a ``mesh`` shards the
-field over a 2-D domain mesh and passes ``mesh=`` to the op, so each call
-runs the halo exchange.
+field the last one produced. The host keeps about ``ahead_s`` seconds of
+dispatches queued ahead of the one it waits for, or as many as the
+chip's free memory holds outputs for, whichever is fewer, so that the
+chip runs on while the host stalls: set-up times a dispatch, and the
+host waits on a one-element probe of each output (a second, tiny
+program on the first shard), so that it holds no field itself. A
+configuration with a ``mesh`` shards the field over a 2-D domain mesh and
+passes ``mesh=`` to the op, so each call runs the halo exchange.
 
 The check compares the window's last dispatch, its input and its output
 at the timed size, with the plain reference (``bench/reference.py``).
@@ -15,6 +19,8 @@ from __future__ import annotations
 
 import collections
 import math
+import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +28,19 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from bench import reference, work
+
+
+# Dispatches timed in set-up to size the queue; the most queued; fields
+# of free memory kept for the running call's input, padded copy and
+# output.
+TIMED_DISPATCHES = 4
+MAX_IN_FLIGHT = 4096
+SPARE_FIELDS = 3
+
+
+def _first_shard(x):
+    """The first addressable shard of ``x``, a single-device array."""
+    return x.addressable_shards[0].data
 
 
 def seed_key(seed: int):
@@ -39,7 +58,8 @@ class StencilSweep:
         self.dtype = jnp.dtype(config["dtype"])
         self.steps = int(traffic["steps_per_call"])
         self.calls_per_dispatch = int(traffic["calls_per_dispatch"])
-        self.in_flight = int(traffic["in_flight"])
+        self.ahead_s = float(traffic["ahead_s"])
+        self.in_flight = 2
         self.mesh = None
         self.dispatches = 0
         self.pending = collections.deque()
@@ -72,15 +92,39 @@ class StencilSweep:
                       out_shardings=sharding)
         self.x = gen(seed_key(self.seed))
         self.fn = jax.jit(self._program)
+        self.probe = jax.jit(lambda a: a[:1, :1])
         warm = self.fn(self.fn(self.x))
+        jax.block_until_ready(self.probe(_first_shard(warm)))
+        t = time.perf_counter()
+        for _ in range(TIMED_DISPATCHES):
+            warm = self.fn(warm)
         jax.block_until_ready(warm)
+        dispatch_s = (time.perf_counter() - t) / TIMED_DISPATCHES
         del warm
         jax.block_until_ready(self.x)
+        self.in_flight = max(2, min(math.ceil(self.ahead_s / dispatch_s),
+                                    self._fields_free()))
+        print(f"queue: {self.in_flight} dispatches of {dispatch_s:.6f} s "
+              "ahead of the one waited for", file=sys.stderr, flush=True)
+
+    def _fields_free(self) -> int:
+        """How many more output fields the fullest chip's free memory
+        holds, less :data:`SPARE_FIELDS`: each queued dispatch holds its
+        output until the next one has run."""
+        field = math.prod(self.shard_shape()) * self.dtype.itemsize
+        free = []
+        for d in self.devices:
+            stats = d.memory_stats() or {}
+            if "bytes_limit" in stats:
+                free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+        if not free:
+            return MAX_IN_FLIGHT
+        return min(free) // field - SPARE_FIELDS
 
     def dispatch(self):
         self.prev = self.x
         self.x = self.fn(self.x)
-        self.pending.append(self.x)
+        self.pending.append(self.probe(_first_shard(self.x)))
         self.dispatches += 1
 
     def throttle(self):

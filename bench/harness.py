@@ -11,7 +11,10 @@ module has one function::
 
 and the driver has ``setup()``, ``dispatch()``, ``throttle()``,
 ``drain()``, ``completed()``, ``work_per_call()``, ``release()`` and
-``check()`` (see ``drivers/stencil_sweep.py``). A metric module has
+``check()`` (see ``drivers/stencil_sweep.py``). ``completed()`` returns
+at least ``calls``; a reader finds all it returns in ``Run.counts``, and
+its optional ``attempted`` and ``failed`` stand in the result line for
+the calls and beside the demotions. A metric module has
 ``read(run) -> float | None``: ``None`` leaves the metric out of the
 line, as when a trace holds nothing it can read.
 """
@@ -56,10 +59,11 @@ class Run:
     peaks: dict                 # the device's row of peaks.json
     setup_s: float
     window_s: float             # host clock, first dispatch to all ready
-    calls: int                  # engine calls completed in the window
-    cell_updates: int           # grid-cell updates completed, all chips
     work: dict                  # per engine call and chip: flops, bytes
     trace: object = None        # trace.Reduced of a --trace 1 run
+    # the driver's whole completed(): calls, and what it counts besides
+    # (cell_updates, tokens, requests, ...), completed in the window
+    counts: dict = dataclasses.field(default_factory=dict)
 
 
 def load_json(path: Path) -> dict:
@@ -240,8 +244,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     correct = all(c["ok"] for c in compared)
 
     run = Run(cell=cell, peaks=peaks, setup_s=setup_s, window_s=window_s,
-              calls=done["calls"], cell_updates=done["cell_updates"],
-              work=driver.work_per_call(), trace=reduced)
+              work=driver.work_per_call(), trace=reduced, counts=done)
     entries = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in entries:
@@ -251,8 +254,10 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": mem}
-    line = {"correct": correct, "attempted": done["calls"],
-            "failed": int(demotions), "metrics": metrics, "device": device}
+    line = {"correct": correct,
+            "attempted": done.get("attempted", done["calls"]),
+            "failed": int(demotions) + done.get("failed", 0),
+            "metrics": metrics, "device": device}
     if reduced is not None:
         device["busy_s"] = reduced.busy_s()
         device["window_s"] = reduced.window_s()
@@ -260,8 +265,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     line["compared"] = {c["name"]: {"value": c["value"], "limit": c["limit"]}
                         for c in compared}
 
-    print(f"window: {window_s:.6f} s, {done['calls']} engine calls, "
-          f"{done['cell_updates']} cell updates; in the window "
+    counted = ", ".join(f"{v} {k}" for k, v in done.items())
+    print(f"window: {window_s:.6f} s, {counted}; in the window "
           f"{counter.traces} traces and {counter.compiles} compiles",
           file=err, flush=True)
     for c in compared:

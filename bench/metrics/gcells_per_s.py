@@ -4,4 +4,4 @@ in billions per second."""
 
 
 def read(run):
-    return run.cell_updates / run.window_s * 1e-9
+    return run.counts["cell_updates"] / run.window_s * 1e-9

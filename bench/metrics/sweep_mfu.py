@@ -10,4 +10,4 @@ def read(run):
         return None
     least = work.least_time_s(run.work["flops"], run.work["bytes"],
                               run.peaks)
-    return 100.0 * run.calls * least / run.trace.window_s()
+    return 100.0 * run.counts["calls"] * least / run.trace.window_s()

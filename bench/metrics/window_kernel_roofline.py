@@ -16,4 +16,4 @@ def read(run):
         return None
     least = work.least_time_s(run.work["flops"], run.work["bytes"],
                               run.peaks)
-    return 100.0 * run.calls * run.cell.chips * least / kernel_s
+    return 100.0 * run.counts["calls"] * run.cell.chips * least / kernel_s

@@ -8,6 +8,9 @@ its start and duration in nanoseconds on the same clock as the host's
 annotations. An op's name is its HLO text. The harness marks its window
 and its host phases with ``bench.*`` annotations.
 
+Its ``XLA Modules`` line holds one event per run of a compiled program,
+named after the jitted function (``jit_prefill(<fingerprint>)``).
+
 Each device op is put in one class:
 
 * ``kernel``: a custom call whose HLO text holds the configuration's
@@ -29,6 +32,7 @@ import re
 import sys
 
 OPS_LINES = ("XLA Ops", "Async XLA Ops")
+MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
 COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
                "all-to-all", "reduce-scatter", "send", "recv")
@@ -48,6 +52,11 @@ def merge(intervals):
 
 def total(intervals) -> int:
     return sum(e - s for s, e in intervals)
+
+
+def intersect(a, b):
+    """``a`` within ``b``, both merged."""
+    return subtract(a, subtract(a, b))
 
 
 def subtract(a, b):
@@ -93,10 +102,18 @@ def classify(name: str, kernel: str) -> str:
 class Device:
     name: str
     ops: list          # (start_ns, end_ns, op name, class), in the window
+    modules: list = dataclasses.field(default_factory=list)
+    # (start_ns, end_ns, program name) of each program run, in the window
 
     def intervals(self, cls=None):
         return merge((s, e) for s, e, _, c in self.ops
                      if cls is None or c == cls)
+
+    def module_intervals(self, prefix: str):
+        """Union of the runs of the programs whose name starts with
+        ``prefix``."""
+        return merge((s, e) for s, e, n in self.modules
+                     if n.startswith(prefix))
 
 
 @dataclasses.dataclass
@@ -125,11 +142,12 @@ class Reduced:
                 yield s, e, d.name
 
     def host_activity(self, s, e) -> str:
-        """The host phase that overlaps ``[s, e)`` the most."""
+        """The host phase that overlaps ``[s, e)`` the most; of phases that
+        overlap it alike, the innermost (the one that starts last)."""
         best, name = 0, "host: outside bench phases"
         for hs, he, hn in self.host:
             ov = min(e, he) - max(s, hs)
-            if ov > best:
+            if ov > 0 and ov >= best:
                 best, name = ov, hn
         return name
 
@@ -159,8 +177,13 @@ def reduce_profile(profile, *, kernel: str) -> Reduced:
     devices = []
     for plane in profile.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            ops = []
+            ops, modules = [], []
             for line in plane.lines:
+                if line.name == MODULES_LINE:
+                    modules.extend(
+                        (int(ev.start_ns),
+                         int(ev.start_ns) + int(ev.duration_ns), ev.name)
+                        for ev in line.events)
                 if line.name not in OPS_LINES:
                     continue
                 for ev in line.events:
@@ -168,7 +191,7 @@ def reduce_profile(profile, *, kernel: str) -> Reduced:
                     ops.append((s, s + int(ev.duration_ns), ev.name,
                                 classify(ev.name, kernel)))
             if ops:
-                devices.append(Device(plane.name, ops))
+                devices.append(Device(plane.name, ops, modules))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
@@ -186,6 +209,8 @@ def reduce_profile(profile, *, kernel: str) -> Reduced:
     for d in devices:
         d.ops = [(max(s, lo), min(e, hi), n, c) for s, e, n, c in d.ops
                  if min(e, hi) > max(s, lo)]
+        d.modules = [(max(s, lo), min(e, hi), n) for s, e, n in d.modules
+                     if min(e, hi) > max(s, lo)]
     devices = [d for d in devices if d.ops]
     if not devices:
         raise ValueError("trace holds no device op inside the window")

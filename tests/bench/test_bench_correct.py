@@ -126,3 +126,25 @@ def test_sharded_program_is_correct_and_exchange_left_out_fails():
     sound, broken, gap = json.loads(r.stdout.strip().splitlines()[-1])
     assert sound is True
     assert broken is False and gap > 1e-3
+
+
+@pytest.mark.parametrize("ahead_s, least", [(0.0, 2), (1e6, 4096)])
+def test_queue_is_sized_from_ahead_s_and_holds_only_probes(
+        ahead_s, least, small_cell):
+    import jax
+
+    from bench import harness
+
+    cell = small_cell("jacobi2d-16k.t4", 128)
+    driver = harness.driver_module(cell).make(
+        cell.config, dict(cell.traffic, ahead_s=ahead_s), seed=3,
+        impl="interpret", devices=jax.devices()[:1])
+    driver.setup()
+    assert driver.in_flight == least
+    for _ in range(6):
+        driver.dispatch()
+        driver.throttle()
+        assert len(driver.pending) <= driver.in_flight
+    assert all(p.size == 1 for p in driver.pending)
+    driver.drain()
+    assert driver.completed()["calls"] == 6

@@ -68,8 +68,9 @@ def _profile(extra_chip1=()):
 def _run(reduced):
     cell = harness.resolve("jacobi2d-48k-x4.t4")
     return harness.Run(cell=dataclasses.replace(cell, chips=2), peaks={},
-                       setup_s=1.0, window_s=1e-6, calls=1, cell_updates=0,
-                       work={"flops": 10, "bytes": 100}, trace=reduced)
+                       setup_s=1.0, window_s=1e-6,
+                       work={"flops": 10, "bytes": 100}, trace=reduced,
+                       counts={"calls": 1, "cell_updates": 0})
 
 
 def _readers():

@@ -83,9 +83,9 @@ def _run(reduced, calls=1, chips=2):
     cell = dataclasses.replace(cell, chips=chips)
     return harness.Run(cell=cell, peaks={"flops_per_s": 1e12,
                                          "hbm_bytes_per_s": 1e12},
-                       setup_s=1.0, window_s=1e-6, calls=calls,
-                       cell_updates=0, work={"flops": 10, "bytes": 100},
-                       trace=reduced)
+                       setup_s=1.0, window_s=1e-6,
+                       work={"flops": 10, "bytes": 100}, trace=reduced,
+                       counts={"calls": calls, "cell_updates": 0})
 
 
 def test_metric_readers_on_a_reduced_trace():
